@@ -44,7 +44,7 @@ import dataclasses
 import math
 import zlib
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -101,7 +101,7 @@ class ChunkedStream:
     original pytree from the reassembled byte blob.
     """
 
-    def __init__(self, stream_id: str, data: bytes,
+    def __init__(self, stream_id: str, data: Union[bytes, memoryview],
                  meta: Optional[List[Tuple[str, str, Tuple[int, ...], int]]]
                  = None, quantum: int = DEFAULT_QUANTUM):
         assert quantum > 0
@@ -137,13 +137,17 @@ class ChunkedStream:
     @classmethod
     def from_pytree(cls, stream_id: str, tree: PyTree,
                     quantum: int = DEFAULT_QUANTUM) -> "ChunkedStream":
-        parts, meta, off = [], [], 0
-        for key, arr in _leaf_records(tree):
-            raw = arr.tobytes()
+        records = _leaf_records(tree)
+        meta, off = [], 0
+        for key, arr in records:
             meta.append((key, arr.dtype.str, tuple(arr.shape), off))
-            parts.append(raw)
-            off += len(raw)
-        return cls(stream_id, b"".join(parts), meta, quantum)
+            off += arr.nbytes
+        raw = [arr.reshape(-1).view(np.uint8) for _, arr in records]
+        # chunk payloads are read-only views of one buffer: a single-leaf
+        # artifact (an optimizer shard) is chunked without any copy
+        data = raw[0] if len(raw) == 1 else \
+            np.concatenate(raw or [np.empty(0, np.uint8)])
+        return cls(stream_id, memoryview(data).toreadonly(), meta, quantum)
 
 
 class StreamAssembler:

@@ -19,7 +19,6 @@ import jax
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map_compat
 
 PyTree = Any
 
@@ -53,9 +52,9 @@ def neighbor_backup(tree: PyTree, pspecs: PyTree, mesh: Mesh,
     def permute_all(*xs):
         return tuple(jax.lax.ppermute(x, axis, perm) for x in xs)
 
-    out = shard_map_compat(
-        permute_all, mesh,
-        in_specs=tuple(specs), out_specs=tuple(specs),
+    out = jax.shard_map(
+        permute_all, mesh=mesh,
+        in_specs=tuple(specs), out_specs=tuple(specs), check_vma=False,
     )(*vals)
 
     new_flat = list(flat_vals)

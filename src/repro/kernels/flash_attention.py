@@ -61,7 +61,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, causal: bool, sq: int,
 
 def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True, bq: int = 128, bk: int = 256,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """q: (B, Sq, H, hd); k/v: (B, Skv, H, hd) (kv already head-repeated).
     Returns (B, Sq, H, hd)."""
     b, sq, h, hd = q.shape
@@ -93,8 +93,8 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention(q, k, v, causal: bool = True, bq: int = 128,
-                    bk: int = 256, interpret: bool = True):
+def flash_attention(q, k, v, causal: bool, bq: int, bk: int,
+                    interpret: bool):
     return flash_attention_fwd(q, k, v, causal=causal, bq=bq, bk=bk,
                                interpret=interpret)
 

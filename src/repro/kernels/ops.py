@@ -1,8 +1,7 @@
-"""jit'd public wrappers for the Pallas kernels.
-
-``interpret`` defaults to True off-TPU (the kernel body executes in Python
-via the Pallas interpreter — correctness path); on TPU backends it compiles
-to Mosaic."""
+"""jit'd public wrappers for the Pallas kernels — the one place that picks
+interpret mode: off-TPU the kernel body executes in Python via the Pallas
+interpreter (correctness path); on TPU backends it compiles to Mosaic. The
+kernel modules take ``interpret`` from their caller, with no default."""
 from __future__ import annotations
 
 import functools
@@ -24,9 +23,9 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int = 128,
     return _fa.flash_attention(q, k, v, causal, bq, bk, _default_interpret())
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "head_tile"))
-def ssd(x, dt, a, b_mat, c_mat, *, chunk: int = 256, head_tile: int = 8):
-    return _ssd.ssd(x, dt, a, b_mat, c_mat, chunk=chunk, head_tile=head_tile,
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def ssd(x, dt, a, b_mat, c_mat, *, chunk: int = 256):
+    return _ssd.ssd(x, dt, a, b_mat, c_mat, chunk=chunk,
                     interpret=_default_interpret())
 
 

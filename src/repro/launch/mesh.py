@@ -9,19 +9,12 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh_compat(shape, axes, devices=None):
-    """jax.make_mesh across jax versions: `axis_types` (and the AxisType
-    enum) only exist on newer releases — pass them when available."""
-    kw = {}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kw["axis_types"] = (axis_type.Auto,) * len(axes)
-    if devices is not None:
-        kw["devices"] = devices
-    return jax.make_mesh(shape, axes, **kw)
-
-
-_make_mesh = make_mesh_compat
+def make_mesh(shape, axes, devices=None):
+    """`jax.make_mesh` with every axis `Auto` (the sharding-in-types default
+    this repo's specs are written against)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -29,21 +22,20 @@ def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = int(np.prod(shape))
-    devices = jax.devices()[:n]
-    if len(devices) < n:
+    found = jax.devices()
+    if len(found) < n:
         raise RuntimeError(
-            f"production mesh needs {n} devices, found {len(devices)} — "
-            "run under XLA_FLAGS=--xla_force_host_platform_device_count=512 "
-            "(launch/dryrun.py sets this)")
-    return _make_mesh(shape, axes, devices=devices)
+            f"production mesh {dict(zip(axes, shape))} needs {n} devices; "
+            f"found {len(found)} {found[0].platform} device(s)")
+    return make_mesh(shape, axes, devices=found[:n])
 
 
 def make_host_mesh(data: int = 2, model: int = 2, pod: int = 1):
     """Small mesh over host-platform devices for smoke tests/examples."""
     shape = (pod, data, model) if pod > 1 else (data, model)
     axes = ("pod", "data", "model") if pod > 1 else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_single_device_mesh():
-    return _make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))

@@ -24,8 +24,10 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro.configs import get_arch, reduce_for_smoke
+    from repro.launch.cache import use_compile_cache
     from repro.models import build_model
 
+    use_compile_cache()
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = reduce_for_smoke(cfg)

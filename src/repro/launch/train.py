@@ -1,12 +1,13 @@
 """Training CLI.
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-0.6b --smoke \\
-        --steps 20 --dp 2 --tp 1 [--inject-failure 10]
+        --steps 20 --dp 2 [--inject-failure 10]
 
-Runs the full stack: controller-indexed data loading, SPMD train step with
+Runs the full stack: controller-indexed data loading, the train step,
 instant checkpointing, the ckpt engine (instant + periodic full), failure
-injection and recovery. Smoke scale by default (this container is CPU-only);
---full uses the production config (requires a real TPU slice).
+injection and recovery. Smoke scale by default (a CPU runs it); --full
+trains the published config, which needs an accelerator (qwen3-0.6b fits
+one TPU v5e chip at --seq-len 128 --global-batch 8; see chip_smoke.py).
 """
 from __future__ import annotations
 
@@ -14,9 +15,19 @@ import argparse
 import dataclasses
 import time
 from pathlib import Path
+from typing import Any, List, Optional, Sequence, Tuple
 
 
-def main() -> None:
+@dataclasses.dataclass
+class TrainRun:
+    """What one CLI run did, for callers that drive `main` in-process.
+    Windows are `(start, end)` on the `time.perf_counter` clock."""
+    cluster: Any
+    step_windows: List[Tuple[float, float]]
+    recoveries: List[Tuple[Any, Tuple[float, float]]]
+
+
+def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=20)
@@ -59,14 +70,16 @@ def main() -> None:
                          "it from neighbor backups (FFTrainer), replay "
                          "compute to rebuild it checkpoint-free, or race "
                          "both per worker")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     from repro.configs import get_arch, reduce_for_smoke
+    from repro.launch.cache import use_compile_cache
     from repro.core.lccl import edge_key
     from repro.optim import AdamWConfig
     from repro.runtime.cluster import (ClusterConfig, FabricConfig,
                                        FaultScript, SimCluster)
 
+    use_compile_cache()
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = reduce_for_smoke(cfg)
@@ -90,6 +103,7 @@ def main() -> None:
         recovery=args.recovery_policy)
 
     t0 = time.time()
+    run = TrainRun(clu, [], [])
     for step in range(args.steps):
         if args.inject_failure is not None and step == args.inject_failure:
             if args.storm is not None:
@@ -103,8 +117,10 @@ def main() -> None:
                 print(f"[failover] injecting failure at step {step}")
                 clu.inject_failure([1], hardware=args.hardware_failure)
             if any(not w.alive for w in clu.workers):
+                t_rec = time.perf_counter()
                 rep = clu.recover(
                     FaultScript(hardware=args.hardware_failure))
+                run.recoveries.append((rep, (t_rec, time.perf_counter())))
                 print(f"[failover] recovered from {rep.recovered_from} "
                       f"({rep.policy} policy) in {rep.total_time:.1f}s "
                       f"(modeled), rollback="
@@ -116,7 +132,9 @@ def main() -> None:
                 # training continues, streams route around the damage
                 print("[failover] storm killed no workers; training on "
                       "through the degraded fabric")
+        t_step = time.perf_counter()
         loss = clu.step()
+        run.step_windows.append((t_step, time.perf_counter()))
         if step % 5 == 0 or step == args.steps - 1:
             print(f"step {clu.iteration:4d} loss {loss:.4f} "
                   f"({(time.time() - t0) / (step + 1):.2f}s/it)")
@@ -143,6 +161,7 @@ def main() -> None:
             moved = sum(clu.topology.edge(*e).n_finished for e in edges)
             print(f"  tier {tier}: {len(edges)} edges, "
                   f"{moved} transfers completed")
+    return run
 
 
 if __name__ == "__main__":

@@ -31,7 +31,9 @@ class AdamWConfig:
 
 
 def adamw_init(params: PyTree) -> Dict[str, PyTree]:
-    f32 = lambda p: p.astype(jnp.float32)
+    # a copy even when params are already fp32: the train step donates the
+    # state, and XLA refuses to donate one buffer twice
+    f32 = lambda p: jnp.array(p, jnp.float32)
     zeros = lambda p: jnp.zeros(p.shape, jnp.float32)
     return {
         "master": jax.tree.map(f32, params),

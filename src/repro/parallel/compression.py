@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map_compat
 
 PyTree = Any
 
@@ -79,12 +78,12 @@ def pod_compressed_value_and_grad(
                             is_leaf=is_p)
     batch_in = jax.tree.map(lambda s: _keep_only_axis(s, axis), batch_pspecs,
                             is_leaf=is_p)
-    return shard_map_compat(
-        local, mesh,
+    return jax.shard_map(
+        local, mesh=mesh,
         in_specs=(param_in, batch_in),
         out_specs=((P(), jax.tree.map(lambda _: P(), {"xent": 0, "aux": 0})),
                    param_in),
-        axis_names={axis},
+        axis_names={axis}, check_vma=False,
     )
 
 

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map_compat
 
 PyTree = Any
 
@@ -82,7 +81,8 @@ def pipeline_forward(
     in_specs = (jax.tree.map(lambda _: P(axis), stacked_params,
                              is_leaf=lambda l: hasattr(l, "shape")),
                 P())
-    fn = shard_map_compat(stage, mesh, in_specs=in_specs, out_specs=P())
+    fn = jax.shard_map(stage, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                       check_vma=False)
     return fn(stacked_params, x)
 
 

@@ -1,7 +1,8 @@
 """DP-ring cluster simulation with REAL training-state movement.
 
-The cluster trains an actual (smoke-scale) model: one jit'd step computes the
-global SPMD step, and the ZeRO-unique optimizer state is split into `dp`
+The cluster trains an actual model (smoke scale on a CPU, published width on
+one chip): one jit'd step computes the global step, and the ZeRO-unique
+optimizer state is split into `dp`
 contiguous shards — worker i owns shard i and, per the paper's neighboring
 redundancy, worker (i+1) % dp holds a copy of it in host RAM (two versions,
 consistency §4.2). Failure/recovery therefore moves REAL bytes and the
@@ -58,7 +59,7 @@ PyTree = Any
 __all__ = [
     "ClusterConfig", "FabricConfig", "FaultScript", "RecoveryError",
     "RecoveryPlan", "RecoveryPolicy", "RecoveryReport", "ReliabilityConfig",
-    "SimCluster", "Worker", "shard_slices",
+    "SimCluster", "Worker", "loop_step", "shard_slices",
 ]
 
 
@@ -129,6 +130,25 @@ def _split_legacy_kwargs(kw: Dict[str, Any],
     cc = dataclasses.replace(cluster or ClusterConfig(), **c_over)
     fc = dataclasses.replace(fabric or FabricConfig(), **f_over)
     return cc, fc
+
+
+def loop_step(model, hp: AdamWConfig) -> Callable:
+    """The cluster's jitted train step, ``(state, batch) -> (state, loss)``,
+    on one device. The state is donated: the step updates it in place, so
+    at published width one copy of the state (not two) is resident."""
+
+    def step(state, batch):
+        (loss, aux), grads = jax.value_and_grad(
+            lambda p: model.loss(p, batch), has_aux=True)(state["params"])
+        lr = cosine_schedule(state["step"], lr=hp.lr,
+                             warmup_steps=hp.warmup_steps,
+                             total_steps=hp.total_steps)
+        _, new_opt = adamw_update(grads, state["opt"], state["step"], hp, lr)
+        new_params = cast_params(new_opt["master"], state["params"])
+        return ({"step": state["step"] + 1, "params": new_params,
+                 "opt": new_opt}, loss)
+
+    return jax.jit(step, donate_argnums=(0,))
 
 
 @dataclass
@@ -237,7 +257,7 @@ class SimCluster:
                    loader=PrefetchingLoader(self.source, self.indexer, w, dp))
             for w in range(dp)
         ]
-        self._step = jax.jit(self._make_step())
+        self._step = loop_step(self.model, self.hp)
         self._opt_meta = None
         self._grad_bytes: Optional[float] = None
         # partial recovery transfers, keyed (failed_wid, target_iteration)
@@ -327,24 +347,6 @@ class SimCluster:
         topo.compile_plan = self.fabric_config.compile_plan
         return topo
 
-    # ------------------------------------------------------------------ #
-    def _make_step(self):
-        model, hp = self.model, self.hp
-
-        def step(state, batch):
-            (loss, aux), grads = jax.value_and_grad(
-                lambda p: model.loss(p, batch), has_aux=True)(state["params"])
-            lr = cosine_schedule(state["step"], lr=hp.lr,
-                                 warmup_steps=hp.warmup_steps,
-                                 total_steps=hp.total_steps)
-            _, new_opt = adamw_update(grads, state["opt"], state["step"],
-                                      hp, lr)
-            new_params = cast_params(new_opt["master"], state["params"])
-            return ({"step": state["step"] + 1, "params": new_params,
-                     "opt": new_opt}, loss)
-
-        return step
-
     def _assemble_batch(self) -> Dict[str, jnp.ndarray]:
         parts = []
         for w in self.workers[:self.active_dp]:
@@ -360,7 +362,9 @@ class SimCluster:
         slices = shard_slices(len(vec), self.dp)
         it = self.iteration
         active = self.active_dp
-        shards = {i: vec[slices[i]].copy() for i in range(active)}
+        # views, not copies: `vec` is this step's own buffer and nothing
+        # writes to a held snapshot
+        shards = {i: vec[slices[i]] for i in range(active)}
         for i, w in enumerate(self.workers[:active]):
             # predecessor's shard lands in this worker's host RAM
             nbr_shard = ({"shard": shards[(i - 1) % active]}

@@ -46,10 +46,28 @@ PyTree = Any
 # --------------------------------------------------------------------------- #
 # Optimizer-vector plumbing (moved from runtime/cluster.py)
 # --------------------------------------------------------------------------- #
+def _host_copy(x) -> np.ndarray:
+    """`np.asarray(x)`, without the copy staying cached on `x`: a device
+    `jax.Array` keeps its host copy for as long as it lives, which for the
+    optimizer state is a second 12 B/param on the host until the next step
+    replaces the state. Reading through a throwaway alias of the same
+    device buffer leaves nothing behind."""
+    if isinstance(x, jax.Array) and len(x.sharding.device_set) == 1:
+        x = jax.make_array_from_single_device_arrays(
+            x.shape, x.sharding, [x.addressable_data(0)])
+    return np.asarray(x)
+
+
 def _flatten_opt(opt: PyTree) -> Tuple[np.ndarray, Any]:
     leaves, treedef = jax.tree_util.tree_flatten(opt)
-    vec = np.concatenate([np.asarray(l, np.float32).ravel() for l in leaves])
     shapes = [(l.shape, l.dtype) for l in leaves]
+    # filled leaf by leaf: the host holds one copy of the state, not two
+    vec = np.empty(sum(int(np.prod(s)) for s, _ in shapes), np.float32)
+    off = 0
+    for l in leaves:
+        n = int(np.prod(l.shape))
+        vec[off:off + n] = _host_copy(l).ravel()
+        off += n
     return vec, (treedef, shapes)
 
 
@@ -58,7 +76,8 @@ def _unflatten_opt(vec: np.ndarray, meta) -> PyTree:
     leaves, off = [], 0
     for shape, dtype in shapes:
         n = int(np.prod(shape))
-        leaves.append(vec[off:off + n].reshape(shape).astype(dtype))
+        leaves.append(vec[off:off + n].reshape(shape).astype(dtype,
+                                                            copy=False))
         off += n
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
@@ -625,13 +644,16 @@ def _execute_neighbor_streams(plan: RecoveryPlan, stream_wids: List[int],
                 f"version {target} missing for layout slice {o}"
             vec[slices[o]] = snap.state["shard"]
     cluster._layout = None         # live numbering is authoritative again
-    new_opt = _unflatten_opt(vec, meta)
-    params = jax.tree.map(
-        lambda m, p: jnp.asarray(m).astype(p.dtype),
-        new_opt["master"], cluster.state["params"])
+    param_dtypes = jax.tree.map(lambda p: p.dtype, cluster.state["params"])
+    # the host vector holds everything the restore needs: release the old
+    # state's device buffers first, or two states share one chip's memory
+    cluster.state = None
+    opt = jax.tree.map(jnp.asarray, _unflatten_opt(vec, meta))
+    # a copy, never an alias of the master (the step donates both)
+    params = jax.tree.map(lambda m, dt: jnp.array(m, dt), opt["master"],
+                          param_dtypes)
     cluster.state = {"step": jnp.asarray(target, jnp.int32),
-                     "params": params, "opt": jax.tree.map(jnp.asarray,
-                                                           new_opt)}
+                     "params": params, "opt": opt}
     cluster.iteration = target
 
     # timeline: network recovery overlaps state loading (§5.2); the
@@ -675,7 +697,8 @@ def _execute_full(plan: RecoveryPlan) -> RecoveryReport:
     eng0.writer.drain()
     it = eng0.latest_full()
     assert it is not None, "no full checkpoint available (insurance gap)"
-    like = jax.tree.map(lambda x: np.asarray(x), cluster.state)
+    like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                        cluster.state)
     restored = eng0.restore_full(it, like)
 
     # integrity: re-chunk the restored artifact and check it against the
@@ -692,6 +715,7 @@ def _execute_full(plan: RecoveryPlan) -> RecoveryReport:
         assert not bad, f"full ckpt it{it}: corrupt chunks {bad}"
         chunks_total = stream.n_chunks
 
+    cluster.state = None           # release the old state's device buffers
     cluster.state = jax.tree.map(jnp.asarray, restored)
     rolled = cluster.iteration - it
     cluster.iteration = it

@@ -66,7 +66,7 @@ def test_ssd_kernel_sweep(b, s, h, p, n, chunk, dtype, rng):
     a = -jnp.asarray(rng.uniform(0.5, 2.0, size=(h,)), jnp.float32)
     bm = jnp.asarray(rng.normal(size=(b, s, n)), dtype)
     cm = jnp.asarray(rng.normal(size=(b, s, n)), dtype)
-    yk, sk = ops.ssd(x, dt, a, bm, cm, chunk=chunk, head_tile=4)
+    yk, sk = ops.ssd(x, dt, a, bm, cm, chunk=chunk)
     yo, so = ssd_ref(x, dt, a, bm, cm, chunk=chunk)
     np.testing.assert_allclose(np.asarray(yk, np.float32),
                                np.asarray(yo, np.float32), **_tol(dtype))

@@ -7,29 +7,6 @@ import textwrap
 
 import pytest
 
-# The subprocess SPMD tests are seconds each on the 0.4.37 floor thanks to
-# repro/compat.py:shard_map_compat; only the all-families dry-run (minutes of
-# jit compiles) keeps the `slow` marker. Partial-manual shard_map still
-# CHECK-fails inside old XLA, so that one test needs AxisType-era jax. The
-# gate is a precise version bound (not a blanket feature-detect skip):
-# jax >= 0.6 is the AxisType-era line the latest-jax CI leg runs green
-# (ROADMAP), and the one whose bundled XLA carries the IsManualSubgroup
-# hlo_sharding_util fix. Dev/rc suffixes are ignored by the digit parse.
-_JAX_FLOOR_FOR_PARTIAL_MANUAL = (0, 6, 0)
-
-
-def _jax_version_tuple():
-    import re
-    jax = pytest.importorskip("jax")
-    return tuple(int(x) for x in re.findall(r"\d+", jax.__version__)[:3])
-
-
-requires_axis_type = pytest.mark.skipif(
-    _jax_version_tuple() < _JAX_FLOOR_FOR_PARTIAL_MANUAL,
-    reason="partial-manual shard_map CHECK-fails in pre-AxisType XLA "
-           "(hlo_sharding_util IsManualSubgroup); needs jax >= "
-           + ".".join(map(str, _JAX_FLOOR_FOR_PARTIAL_MANUAL)))
-
 
 def _run(script: str, timeout: int = 560) -> str:
     env = {"XLA_FLAGS": "--xla_force_host_platform_device_count=8",
@@ -50,8 +27,8 @@ def test_neighbor_backup_is_ring_permute():
     from jax.sharding import PartitionSpec as P, NamedSharding
     from repro.core.instant import neighbor_backup
 
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     x = jnp.arange(8, dtype=jnp.float32).reshape(4, 2)  # row r on data-rank r
     xs = jax.device_put(x, NamedSharding(mesh, P("data", "model")))
 
@@ -74,8 +51,8 @@ def test_razor_plan_on_mesh():
     from repro.core.razor import razor_plan
     from repro.train.state import make_state_plan
 
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = reduce_for_smoke(get_arch("llama3-8b"))
     model = build_model(cfg)
     plan = make_state_plan(model, mesh)
@@ -102,8 +79,8 @@ def test_train_step_backup_roundtrip():
     from repro.train.state import init_state
     from repro.train.step import build_train_step
 
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = dataclasses.replace(reduce_for_smoke(get_arch("qwen3-0.6b")),
                               dtype="float32")
     model = build_model(cfg)
@@ -150,7 +127,6 @@ def test_train_step_backup_roundtrip():
     """)
 
 
-@requires_axis_type
 def test_cross_pod_compression_close_to_exact():
     """int8 cross-pod gradient mean with error feedback ~= exact mean.
 
@@ -165,8 +141,8 @@ def test_cross_pod_compression_close_to_exact():
     from repro.train.state import init_state
     from repro.train.step import build_train_step
 
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((2, 4, 1), ("pod", "data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4, 1), ("pod", "data", "model"))
     cfg = dataclasses.replace(reduce_for_smoke(get_arch("gemma-2b")),
                               dtype="float32")
     model = build_model(cfg)
@@ -203,8 +179,8 @@ def test_small_mesh_dryrun_all_families():
     from repro.train.state import make_state_specs
     from repro.train.serve import build_decode_step
 
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     for arch in ("deepseek-67b", "qwen3-moe-30b-a3b", "mamba2-2.7b",
                  "zamba2-7b", "whisper-small", "internvl2-26b"):
         cfg = reduce_for_smoke(get_arch(arch))

@@ -4,11 +4,6 @@ import subprocess
 import sys
 import textwrap
 
-import pytest
-
-# a single ~4 s subprocess run since shard_map_compat fixed it on the 0.4.37
-# floor — cheap enough for the fast CI job (no blanket `slow` skip)
-
 
 def test_pipeline_matches_sequential():
     env_script = """
@@ -17,8 +12,8 @@ def test_pipeline_matches_sequential():
     from jax.sharding import PartitionSpec as P
     from repro.parallel.pipeline import bubble_fraction, pipeline_forward
 
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((4,), ("pipe",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("pipe",))
     L, D, M, MB, S = 8, 16, 6, 2, 4
     rng = np.random.default_rng(0)
     params = {"w": jnp.asarray(rng.normal(size=(L, D, D)) * 0.3, jnp.float32)}
