@@ -4,16 +4,18 @@
     python chip_smoke.py --chips 4    # four chips: the sharded step's backup
 
 One chip (the default) drives the training CLI in-process
-(`repro.launch.train.main`) on qwen3-0.6b at full width: a few steps, a
-software failure of worker 1, recovery from its neighbour's backup with the
-default stream policy, and training on. It then frees that run, trains the
-same seed uninterrupted, and requires the losses after recovery to match.
+(`repro.launch.train.main`) on qwen3-0.6b at full width with the CLI's
+default fabric: a few steps, a software failure of worker 1, recovery from
+its neighbour's backup with the default stream policy, and training on. It
+then frees that run, trains the same seed uninterrupted, and requires the
+losses to be bitwise equal.
 
 `--chips 4` runs only the cross-chip path: `build_train_step` on a
 ("data", "model") = (4, 1) mesh with the state initialised straight into
 its shardings, a few steps, a bitwise check that every backup leaf is the
-new optimizer shard rolled by one data rank, and the first step's loss
-against the same step on one device with the instant checkpoint off.
+new optimizer shard rolled by one data rank, and the same steps on one
+device with the instant checkpoint off: every loss, and the first moment
+after the first update, must agree.
 
 Both need a TPU and stop with an error naming the platform found anywhere
 else. The last line printed is the JSON verdict.
@@ -25,9 +27,11 @@ import dataclasses
 import gc
 import json
 import math
+import os
 import resource
 import sys
 import tempfile
+import threading
 import time
 from pathlib import Path
 from typing import List, Sequence, Tuple
@@ -38,15 +42,15 @@ ARCH = "qwen3-0.6b"
 # donated 8.35 GB state (its memory_analysis); 8 x 256 does not fit 16 GB
 BATCH, SEQ = 8, 128
 STEPS, FAIL_AT = 5, 3
-# the modeled fabric must drain each step's instant checkpoint within its
-# 0.05 s modeled iteration, or its backlog of in-flight chunks pins ~7 GB
-# of host RAM per step at this width (a 40 GiB host ran out at the 4th
-# step with the CLI's 2 workers on 50 GB/s edges). 4 workers on 200 GB/s
-# edges (v5e's published 1,600 Gbit/s chip-to-chip rate) move 5.4 GB per
-# edge per step: 27 ms.
-FABRIC = ["--dp", "4", "--link-bw", "200e9"]
 STEPS_4 = 3
-BF16_RTOL = 2.0 ** -7
+# 4 chips against 1 device: the same math reduced in another order. The
+# first moment after one step is the gradient itself: a correct reduction
+# differs in bf16 rounding only, a step whose gradient comes from one
+# chip's quarter of the batch differs by O(1). Adam's update is near
+# sign(g), so the losses barely see that fault; their bound is a coarse
+# one. Readings behind both limits: CHANGES.md
+LOSS_ATOL = 1e-3
+MOMENT_RTOL = 1e-1
 
 
 def fail(msg: str) -> None:
@@ -89,6 +93,34 @@ class CompileLog:
         return sum(s for _, s in self.events)
 
 
+class RssSampler:
+    """This process's resident set size, read from /proc/self/statm every
+    20 ms on a thread, so each step window reports its own peak."""
+
+    def __init__(self, period: float = 0.02):
+        self.samples: List[Tuple[float, int]] = []
+        self._page = os.sysconf("SC_PAGE_SIZE")
+        self._stop = threading.Event()
+        self._thread = threading.Thread(target=self._run, args=(period,),
+                                        daemon=True)
+        self._thread.start()
+
+    def _run(self, period: float) -> None:
+        with open("/proc/self/statm") as f:
+            while not self._stop.is_set():
+                f.seek(0)
+                rss = int(f.read().split()[1]) * self._page
+                self.samples.append((time.perf_counter(), rss))
+                self._stop.wait(period)
+
+    def peak(self, lo: float, hi: float) -> int:
+        return max((b for t, b in self.samples if lo <= t <= hi), default=0)
+
+    def stop(self) -> None:
+        self._stop.set()
+        self._thread.join()
+
+
 def host_peak_rss() -> int:
     return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
 
@@ -104,11 +136,11 @@ def release(run) -> None:
         w.engine.close()
 
 
-def one_chip(devs, log: CompileLog) -> None:
+def one_chip(devs, log: CompileLog, rss: RssSampler) -> None:
     import jax
     import numpy as np
     argv = ["--arch", ARCH, "--full", "--steps", str(STEPS), "--seq-len",
-            str(SEQ), "--global-batch", str(BATCH), *FABRIC]
+            str(SEQ), "--global-batch", str(BATCH)]
 
     run = train(argv + ["--inject-failure", str(FAIL_AT)])
     clu = run.cluster
@@ -116,11 +148,13 @@ def one_chip(devs, log: CompileLog) -> None:
     n_bytes = sum(x.nbytes for x in jax.tree.leaves(clu.state))
     print(f"model: {ARCH} full width, {n_params} params, "
           f"state {n_bytes} bytes on the device, "
-          f"batch {BATCH}x{SEQ} tokens")
+          f"batch {BATCH}x{SEQ} tokens, {clu.dp} workers")
     walls = [hi - lo for lo, hi in run.step_windows]
-    for i, (w, loss) in enumerate(zip(walls, clu.loss_history)):
-        print(f"step {i}: loss {loss!r}, wall {w:.3f} s "
-              f"(train step + instant-ckpt host copy, until ready)")
+    for i, ((lo, hi), loss) in enumerate(zip(run.step_windows,
+                                             clu.loss_history)):
+        print(f"step {i}: loss {loss!r}, wall {hi - lo:.3f} s "
+              f"(train step + instant-ckpt host copy, until ready), "
+              f"host RSS peak {rss.peak(lo, hi)} bytes")
     print(f"compile: first step {walls[0]:.3f} s wall; "
           f"{len(log.events)} executables built in "
           f"{log.seconds():.3f} s so far")
@@ -137,16 +171,18 @@ def one_chip(devs, log: CompileLog) -> None:
           f"{rep.chunks_sent}/{rep.chunks_total} chunks "
           f"({rep.state_bytes_streamed:.0f} bytes) streamed; "
           f"wall {rec_window[1] - rec_window[0]:.3f} s, "
+          f"host RSS peak {rss.peak(*rec_window)} bytes, "
           f"modeled fabric time {rep.total_time:.3f} s")
+    superseded = sum(w.engine.superseded_chunks for w in clu.workers)
+    print(f"instant checkpoint on the modeled fabric: hidden "
+          f"{clu.instant_hidden}, exposed {clu.instant_exposed} of "
+          f"{STEPS} steps; {superseded} stale chunks superseded")
     stats = devs[0].memory_stats() or {}
     print(f"device memory: peak_bytes_in_use "
           f"{stats.get('peak_bytes_in_use', 'not reported')}; host peak "
           f"RSS so far {host_peak_rss()} bytes")
     if rep.recovered_from != "neighbor" or rep.rolled_back_iterations != 0:
         fail("recovery did not restore from the neighbour with rollback 0")
-    if clu.instant_exposed:
-        fail(f"the modeled fabric left {clu.instant_exposed} instant "
-             f"checkpoints undrained at an iteration boundary")
     if clu.iteration != STEPS or not all(map(math.isfinite,
                                              clu.loss_history)):
         fail(f"training did not reach {STEPS} finite steps")
@@ -164,9 +200,10 @@ def one_chip(devs, log: CompileLog) -> None:
     print(f"largest loss difference after recovery: "
           f"{max(diff[FAIL_AT:])!r} (before the failure: "
           f"{max(diff[:FAIL_AT])!r})")
-    if len(ref_losses) != STEPS or any(
-            d > BF16_RTOL * abs(b) for d, b in zip(diff, ref_losses)):
-        fail("losses after recovery disagree with the uninterrupted run")
+    # one device, one seed, the same data order: a correct restore
+    # continues bit for bit
+    if ref_losses != losses:
+        fail("losses disagree with the uninterrupted run")
 
     # the bare train step, for the share of a loop step spent on the host
     clu = ref.cluster
@@ -199,30 +236,37 @@ def four_chips(devs) -> None:
     cfg = dataclasses.replace(get_arch(ARCH), remat_policy="none")
     model = build_model(cfg)
     shape = ShapeConfig("smoke", SEQ, BATCH, "train")
-    hp = AdamWConfig(warmup_steps=5, total_steps=10)
+    # no warm-up: the first update moves the weights, so later losses
+    # depend on the reduced gradient
+    hp = AdamWConfig(warmup_steps=0, total_steps=10)
     rng = np.random.default_rng(0)
     tokens = [rng.integers(0, cfg.vocab_size, (BATCH, SEQ + 1), np.int32)
               for _ in range(STEPS_4)]
 
-    def first_losses(mesh, instant_ckpt: bool, steps: int, check_backup):
+    def run_steps(mesh, instant_ckpt: bool, check_backup):
+        """Losses, step walls, and the first moment after the first step
+        (on the host)."""
         art = build_train_step(model, mesh, hp, shape=shape,
                                instant_ckpt=instant_ckpt)
         init = jax.jit(lambda k: init_state(model, k),
                        out_shardings=to_named(art.plan.state_pspecs, mesh))
         in_sh = to_named(art.input_pspecs, mesh)
-        losses, walls = [], []
+        losses, walls, m1 = [], [], None
         with mesh:
             state = init(jax.random.key(0))
-            for t in tokens[:steps]:
+            for t in tokens:
                 batch = jax.device_put({"tokens": jnp.asarray(t)}, in_sh)
                 t0 = time.perf_counter()
                 state, metrics, backup = art.step_fn(state, batch)
                 jax.block_until_ready((state, metrics, backup))
                 walls.append(time.perf_counter() - t0)
                 losses.append(float(metrics["loss"]))
+                if m1 is None:
+                    m1 = [np.asarray(x) for x in
+                          jax.tree.leaves(state["opt"]["m"])]
             if check_backup:
                 check_backup(art, state, backup)
-        return losses, walls
+        return losses, walls, m1
 
     def check_backup(art, state, backup):
         is_p = lambda x: isinstance(x, P) or x is None
@@ -252,20 +296,28 @@ def four_chips(devs) -> None:
               f"rolled by one data rank, bitwise")
 
     mesh4 = make_mesh((4, 1), ("data", "model"), devices=devs[:4])
-    losses4, walls4 = first_losses(mesh4, True, STEPS_4, check_backup)
+    losses4, walls4, m4 = run_steps(mesh4, True, check_backup)
     print(f"4 chips, instant ckpt on: losses {losses4}, step walls "
           f"{walls4} s (the first includes compile)")
     print(f"device memory, chip 0: peak_bytes_in_use "
           f"{(devs[0].memory_stats() or {}).get('peak_bytes_in_use')}")
     gc.collect()
     mesh1 = make_mesh((1, 1), ("data", "model"), devices=devs[:1])
-    losses1, _ = first_losses(mesh1, False, 1, None)
-    d = abs(losses4[0] - losses1[0])
-    print(f"1 device, instant ckpt off: first loss {losses1[0]!r}; "
-          f"difference {d!r} (limit {BF16_RTOL * abs(losses1[0])!r})")
-    if not all(map(math.isfinite, losses4)) or d > BF16_RTOL * abs(
-            losses1[0]):
-        fail("4-chip first-step loss disagrees with one device")
+    losses1, _, m1 = run_steps(mesh1, False, None)
+    dl = [abs(a - b) for a, b in zip(losses4, losses1)]
+    sq = [(float(np.vdot(a - b, a - b)), float(np.vdot(b, b)))
+          for a, b in zip(m4, m1)]
+    rel_m = math.sqrt(sum(d for d, _ in sq) / sum(r for _, r in sq))
+    worst = max((math.sqrt(d / r), i) for i, (d, r) in enumerate(sq) if r)
+    print(f"first moment, worst leaf: relative L2 difference {worst[0]!r} "
+          f"(leaf {worst[1]} of {len(sq)}, shape {m1[worst[1]].shape})")
+    print(f"1 device, instant ckpt off: losses {losses1}; differences "
+          f"{dl} (limit {LOSS_ATOL}); first moment after step 1: relative "
+          f"L2 difference {rel_m!r} (limit {MOMENT_RTOL})")
+    if not all(map(math.isfinite, losses4)) or max(dl) > LOSS_ATOL:
+        fail("4-chip losses disagree with one device")
+    if not rel_m <= MOMENT_RTOL:
+        fail("4-chip gradient (first moment) disagrees with one device")
 
 
 def main() -> None:
@@ -283,11 +335,15 @@ def main() -> None:
           f"x{len(devs)}; compile cache {use_compile_cache()}; host peak "
           f"RSS with the runtime up {host_peak_rss()} bytes")
     log = CompileLog()
+    rss = RssSampler()
     t0 = time.perf_counter()
-    if args.chips == 4:
-        four_chips(devs)
-    else:
-        one_chip(devs, log)
+    try:
+        if args.chips == 4:
+            four_chips(devs)
+        else:
+            one_chip(devs, log, rss)
+    finally:
+        rss.stop()
     print(f"wall {time.perf_counter() - t0:.1f} s; host peak RSS "
           f"{host_peak_rss()} bytes")
     print(json.dumps({"ok": True, "device": {

@@ -58,6 +58,7 @@ class CkptEngine:
         self.full_count = 0
         self.streamed_chunks = 0
         self.streamed_bytes = 0
+        self.superseded_chunks = 0     # stale instant chunks never sent
         self.last_instant_ticket: Optional[StreamTicket] = None
 
     # ---------------- chunk-stream plumbing ---------------- #
@@ -125,6 +126,13 @@ class CkptEngine:
         else:
             self.neighbor.push(iteration, neighbor_backup)
             self.instant_count += 1
+            # a newer version supersedes a stale one still in flight: its
+            # chunks that never reached the wire are withdrawn, so a fabric
+            # slower than the iteration carries one version per worker
+            # instead of a backlog that grows every step
+            stale = self.last_instant_ticket
+            if stale is not None and not stale.complete:
+                self.superseded_chunks += self.transport.withdraw(stale)
             self.last_instant_ticket = self._stream(
                 f"instant/it{iteration:08d}/w{self.worker_id:05d}",
                 neighbor_backup, t, route="instant")

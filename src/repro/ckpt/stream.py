@@ -144,7 +144,8 @@ class ChunkedStream:
             off += arr.nbytes
         raw = [arr.reshape(-1).view(np.uint8) for _, arr in records]
         # chunk payloads are read-only views of one buffer: a single-leaf
-        # artifact (an optimizer shard) is chunked without any copy
+        # artifact (an optimizer shard) is chunked without any copy, and
+        # any chunk still in flight keeps that whole buffer alive
         data = raw[0] if len(raw) == 1 else \
             np.concatenate(raw or [np.empty(0, np.uint8)])
         return cls(stream_id, memoryview(data).toreadonly(), meta, quantum)
@@ -373,6 +374,29 @@ class _NackingTransport:
         return chunks, StreamTicket(stream.stream_id, [], chunks, assembler,
                                     submitted_at=t)
 
+    def withdraw(self, ticket: StreamTicket) -> int:
+        """Take back the chunks of `ticket` that have not started moving
+        (chunks on the wire run to delivery, so no byte is un-sent) and
+        return how many. The ticket stays incomplete: its stream never
+        lands in full. Withdrawn bytes leave the STATE byte count."""
+        gone = self._dequeue_unstarted(ticket)
+        self.state_bytes_submitted -= sum(p.chunk.nbytes for p in gone)
+        return len(gone)
+
+    def _dequeue_unstarted(self, ticket: StreamTicket
+                           ) -> List["_PendingChunk"]:
+        """Cancel and drop the in-flight chunks of `ticket` whose transfer
+        has not moved a byte yet; returns them in submission order."""
+        gone = [p for p in self._pending
+                if p.ticket is ticket and self._cancel(p.transfer)]
+        if gone:
+            ids = {id(p) for p in gone}
+            self._pending = [p for p in self._pending if id(p) not in ids]
+        return gone
+
+    def _cancel(self, transfer: Any) -> bool:
+        raise NotImplementedError
+
     def _drain_links(self) -> float:
         raise NotImplementedError
 
@@ -491,6 +515,9 @@ class StreamTransport(_NackingTransport):
         busy = self.scheduler.run(until)
         self.pump()
         return busy
+
+    def _cancel(self, transfer: Transfer) -> bool:
+        return self.scheduler.cancel(transfer)
 
     def _drain_links(self) -> float:
         return self.scheduler.drain()
@@ -719,18 +746,10 @@ class TopologyTransport(_NackingTransport):
 
     def _rebalance_stripe(self, st: _StripeState, t: float) -> int:
         st.epoch = self.topology.epoch
-        withdrawn: List[Tuple[_PendingChunk, PathTransfer]] = []
-        for pend in self._pending:
-            if pend.ticket is st.ticket and \
-                    isinstance(pend.transfer, PathTransfer):
-                old = pend.transfer
-                if self.topology.cancel_path(old):
-                    withdrawn.append((pend, old))
+        withdrawn = [(p, p.transfer)
+                     for p in self._dequeue_unstarted(st.ticket)]
         if not withdrawn:
             return 0
-        gone_pend = {id(p) for p, _ in withdrawn}
-        self._pending = [p for p in self._pending
-                         if id(p) not in gone_pend]
         gone_tr = {id(old) for _, old in withdrawn}
         st.ticket.transfers = [tr for tr in st.ticket.transfers
                                if id(tr) not in gone_tr]
@@ -813,6 +832,9 @@ class TopologyTransport(_NackingTransport):
         busy = self.topology.run(until)
         self.pump()
         return busy
+
+    def _cancel(self, transfer: PathTransfer) -> bool:
+        return self.topology.cancel_path(transfer)
 
     def _drain_links(self) -> float:
         self._maybe_rebalance()

@@ -46,27 +46,15 @@ PyTree = Any
 # --------------------------------------------------------------------------- #
 # Optimizer-vector plumbing (moved from runtime/cluster.py)
 # --------------------------------------------------------------------------- #
-def _host_copy(x) -> np.ndarray:
-    """`np.asarray(x)`, without the copy staying cached on `x`: a device
-    `jax.Array` keeps its host copy for as long as it lives, which for the
-    optimizer state is a second 12 B/param on the host until the next step
-    replaces the state. Reading through a throwaway alias of the same
-    device buffer leaves nothing behind."""
-    if isinstance(x, jax.Array) and len(x.sharding.device_set) == 1:
-        x = jax.make_array_from_single_device_arrays(
-            x.shape, x.sharding, [x.addressable_data(0)])
-    return np.asarray(x)
-
-
 def _flatten_opt(opt: PyTree) -> Tuple[np.ndarray, Any]:
     leaves, treedef = jax.tree_util.tree_flatten(opt)
     shapes = [(l.shape, l.dtype) for l in leaves]
-    # filled leaf by leaf: the host holds one copy of the state, not two
+    # filled leaf by leaf, with no concatenated temporaries
     vec = np.empty(sum(int(np.prod(s)) for s, _ in shapes), np.float32)
     off = 0
     for l in leaves:
         n = int(np.prod(l.shape))
-        vec[off:off + n] = _host_copy(l).ravel()
+        vec[off:off + n] = np.asarray(l).ravel()
         off += n
     return vec, (treedef, shapes)
 

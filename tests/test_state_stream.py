@@ -78,6 +78,35 @@ def test_transport_delivers_through_scheduler():
     np.testing.assert_array_equal(asm.to_pytree(tree)["a"], tree["a"])
 
 
+@pytest.mark.parametrize("fabric", ["link", "topology"])
+def test_withdraw_takes_back_only_unstarted_chunks(fabric):
+    """Withdrawal dequeues the chunks that never moved a byte; the chunk on
+    the wire and those already delivered stay, and the ticket stays
+    incomplete. The withdrawn bytes leave the STATE byte count."""
+    from repro.ckpt.stream import TopologyTransport
+    from repro.core.lccl import LinkTopology
+    if fabric == "link":
+        tp = StreamTransport(LinkScheduler(1e6, quantum=256))
+        send = {}
+    else:
+        tp = TopologyTransport(LinkTopology(4, 1e6, quantum=256))
+        send = {"src": 0, "dst": 1, "policy": "shortest"}
+    tree = _tree()
+    cs = ChunkedStream.from_pytree("s", tree, quantum=512)
+    asm = StreamAssembler.for_stream(cs)
+    ticket = tp.send(cs, 0.0, assembler=asm, **send)
+    tp.run(until=0.00155)        # 3 chunks of 512 B at 1 MB/s, then half one
+    landed = asm.received
+    assert 0 < landed < cs.n_chunks - 1
+    gone = tp.withdraw(ticket)
+    assert gone == cs.n_chunks - landed - 1   # one chunk is mid-flight
+    assert tp.state_bytes_submitted == cs.total_bytes - sum(
+        cs.chunks[i].nbytes for i in range(landed + 1, cs.n_chunks))
+    assert tp.withdraw(ticket) == 0
+    tp.drain()
+    assert asm.received == landed + 1 and not ticket.complete
+
+
 def test_train_traffic_delays_stream_completion():
     def finish(with_train):
         tp = StreamTransport(LinkScheduler(1e6, quantum=256))
@@ -113,6 +142,29 @@ def test_engine_paths_stream_chunks(tmp_path):
     man = load_manifest(eng._full_path(2))
     assert man is not None and man["n_chunks"] >= 1
     eng.writer.drain()
+    eng.close()
+
+
+def test_newer_instant_stream_supersedes_stale_one(tmp_path):
+    """On a link slower than the iteration, each instant checkpoint takes
+    back the unsent chunks of the one before it: at most one version per
+    worker is queued, and the newest still lands whole."""
+    tp = StreamTransport(LinkScheduler(1e6, quantum=256))
+    eng = CkptEngine(CkptEngineConfig(out_dir=tmp_path, quantum=512),
+                     worker_id=0, transport=tp)
+    shard = {"shard": np.arange(2000, dtype=np.float32)}   # 16 chunks
+    tickets = []
+    for it in range(1, 4):
+        eng.on_step(it, shard, shard, t=(it - 1) * 1e-3)
+        tickets.append(eng.last_instant_ticket)
+        tp.run(until=it * 1e-3)
+        queued = {id(p.ticket) for p in tp._pending}
+        assert len(queued) <= 2           # the newest + one chunk on the wire
+    assert eng.superseded_chunks > 0
+    assert not any(tk.complete for tk in tickets[:-1])
+    tp.drain()
+    assert eng.last_instant_ticket.complete
+    assert eng.last_instant_ticket.assembler.complete
     eng.close()
 
 
@@ -286,3 +338,29 @@ def test_instant_ckpt_hidden_on_fast_link(tmp_path):
     assert clu.instant_hidden == 4
     assert clu.instant_exposed == 0
     assert clu.transport.chunks_delivered > 0
+
+
+def test_instant_backlog_bounded_on_slow_fabric(tmp_path):
+    """A fabric too slow to drain the instant checkpoint in an iteration
+    exposes every step, but stale versions are superseded instead of
+    queueing: the in-flight chunks never exceed one shard per worker plus
+    the chunks on the wire, and training and recovery are unchanged."""
+    import jax
+    slow = _mk_cluster(tmp_path / "slow", link_bw=2e6)
+    for _ in range(5):
+        slow.step()
+        newest = sum(w.engine.last_instant_ticket.assembler.n_chunks
+                     for w in slow.workers)
+        # one chunk at most on the wire per ring edge
+        assert len(slow.transport._pending) <= newest + slow.dp
+    assert slow.instant_exposed == 5
+    assert sum(w.engine.superseded_chunks for w in slow.workers) > 0
+    fast = _mk_cluster(tmp_path / "fast")
+    fast.run(5)
+    assert slow.loss_history == fast.loss_history
+    slow.inject_failure([1])
+    rep = slow.recover()
+    assert rep.recovered_from == "neighbor"
+    assert rep.rolled_back_iterations == 0
+    for x, y in zip(jax.tree.leaves(fast.state), jax.tree.leaves(slow.state)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
