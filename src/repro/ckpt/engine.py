@@ -15,7 +15,6 @@ shards ride the adjacent ICI ring edge, lazy backups fan out onto whichever
 tier has slack, full fallbacks take the least-loaded live edge."""
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Any, Callable, Dict, Optional
@@ -28,6 +27,7 @@ from repro.ckpt.storage import (AsyncWriter, load_meta, load_pytree,
 from repro.ckpt.stream import (DEFAULT_QUANTUM, ChunkedStream, StreamAssembler,
                                StreamTicket, StreamTransport)
 from repro.core.consistency import SnapshotKeeper
+from repro.launch.spans import count, span
 
 PyTree = Any
 
@@ -55,9 +55,7 @@ class CkptEngine:
         self.writer = AsyncWriter()
         self.transport = transport
         self.instant_count = 0
-        self.full_count = 0
         self.streamed_chunks = 0
-        self.streamed_bytes = 0
         self.superseded_chunks = 0     # stale instant chunks never sent
         self.last_instant_ticket: Optional[StreamTicket] = None
 
@@ -88,11 +86,12 @@ class CkptEngine:
             policy = "shortest"
         elif route == "lazy":
             src = self.worker_id
-        ticket = self.transport.send(stream, t, assembler=asm, src=src,
-                                     dst=dst, policy=policy,
-                                     k=self.cfg.route_k)
+        with span("stream.send"):
+            ticket = self.transport.send(stream, t, assembler=asm, src=src,
+                                         dst=dst, policy=policy,
+                                         k=self.cfg.route_k)
+            count("chunks", stream.n_chunks)
         self.streamed_chunks += stream.n_chunks
-        self.streamed_bytes += stream.total_bytes
         return ticket
 
     def export_stream(self, iteration: int, which: str = "own"
@@ -132,7 +131,10 @@ class CkptEngine:
             # instead of a backlog that grows every step
             stale = self.last_instant_ticket
             if stale is not None and not stale.complete:
-                self.superseded_chunks += self.transport.withdraw(stale)
+                with span("stream.withdraw"):
+                    gone = self.transport.withdraw(stale)
+                    count("chunks", gone)
+                self.superseded_chunks += gone
             self.last_instant_ticket = self._stream(
                 f"instant/it{iteration:08d}/w{self.worker_id:05d}",
                 neighbor_backup, t, route="instant")
@@ -151,7 +153,6 @@ class CkptEngine:
                                 {"iteration": iteration,
                                  "worker": self.worker_id})
         if ok:
-            self.full_count += 1
             # the full fallback rides the same link as everything else; its
             # manifest lets a partial restore verify + resume per chunk
             sid = f"full/it{iteration:08d}/w{self.worker_id:05d}"

@@ -12,6 +12,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import numpy as np
 
+from repro.launch.spans import count, span
+
 PyTree = Any
 _SEP = "|"
 
@@ -28,10 +30,12 @@ def _flatten(tree: PyTree) -> Dict[str, np.ndarray]:
 def save_pytree(path: Path, tree: PyTree, meta: Optional[Dict] = None) -> None:
     path = Path(path)
     path.parent.mkdir(parents=True, exist_ok=True)
-    flat = _flatten(tree)
-    tmp = path.with_suffix(".tmp.npz")
-    np.savez(tmp, **flat)
-    tmp.rename(path)                      # atomic-ish publish
+    with span("storage.save"):
+        flat = _flatten(tree)
+        tmp = path.with_suffix(".tmp.npz")
+        np.savez(tmp, **flat)
+        tmp.rename(path)                  # atomic-ish publish
+        count("bytes", sum(a.nbytes for a in flat.values()))
     if meta is not None:
         path.with_suffix(".json").write_text(json.dumps(meta))
 

@@ -50,6 +50,7 @@ import numpy as np
 
 from repro.core.lccl import (Edge, LinkScheduler, LinkTopology, PathTransfer,
                              RoutingError, Transfer, edge_key)
+from repro.launch.spans import count, span
 
 PyTree = Any
 DEFAULT_QUANTUM = 1 << 20          # 1 MiB — the paper's chunk granularity
@@ -111,11 +112,14 @@ class ChunkedStream:
         self.total_bytes = len(data)
         n = max(1, math.ceil(len(data) / quantum))
         self.chunks: List[StreamChunk] = []
-        for i in range(n):
-            payload = data[i * quantum:(i + 1) * quantum]
-            self.chunks.append(StreamChunk(
-                stream_id, i, n, i * quantum, payload,
-                zlib.crc32(payload), self.total_bytes))
+        with span("stream.chunk"):
+            for i in range(n):
+                payload = data[i * quantum:(i + 1) * quantum]
+                self.chunks.append(StreamChunk(
+                    stream_id, i, n, i * quantum, payload,
+                    zlib.crc32(payload), self.total_bytes))
+            count("bytes", self.total_bytes)
+            count("chunks", n)
 
     @property
     def n_chunks(self) -> int:
@@ -410,10 +414,11 @@ class _NackingTransport:
         chains complete inside one `_drain_links` call); the loop here only
         re-runs for chunks the delivery step re-submitted (CRC-rejected
         NACK resends), so it is bounded by `max_retransmits`."""
-        for _ in range(max_rounds):
-            t = self._drain_links()
-            if self.pump() == 0 and self._links_idle():
-                return t
+        with span("fabric.drain"):
+            for _ in range(max_rounds):
+                t = self._drain_links()
+                if self.pump() == 0 and self._links_idle():
+                    return t
         raise RuntimeError(f"{type(self).__name__}.drain did not converge "
                            "(unbounded retransmission?)")
 

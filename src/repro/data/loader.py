@@ -19,6 +19,7 @@ from typing import Deque, Dict, Optional, Tuple
 import numpy as np
 
 from repro.data.indexer import TidIndexer
+from repro.launch.spans import count, span
 
 
 def buffer_bytes(seq_len: int, batch_per_rank: int, k: int, phi: float,
@@ -83,22 +84,22 @@ class PrefetchingLoader:
         self.k = k
         self.byte_limit = byte_limit
         self._buf: Deque[BufferedBatch] = collections.deque()
-        self.preload_bytes_total = 0
 
     # ---- naming resolution: TID -> buffered batch (paper's get_item) ---- #
     def get(self, iteration: int) -> np.ndarray:
-        while self._buf and self._buf[0].iteration < iteration:
-            self._buf.popleft()                      # evict consumed
-        if not self._buf or self._buf[0].iteration != iteration:
-            self._load(iteration)                    # demand miss (recovery)
-        batch = self._buf.popleft()
-        assert batch.iteration == iteration
+        with span("data.batch"):
+            while self._buf and self._buf[0].iteration < iteration:
+                self._buf.popleft()                  # evict consumed
+            if not self._buf or self._buf[0].iteration != iteration:
+                self._load(iteration)                # demand miss (recovery)
+            batch = self._buf.popleft()
+            assert batch.iteration == iteration
+            count("rows", len(batch.tokens))
         return batch.tokens
 
     def _load(self, iteration: int) -> None:
         idx = self.indexer.indices(iteration, self.dp_rank, self.active_dp)
         self._buf.appendleft(BufferedBatch(iteration, self.source.fetch(idx)))
-        self.preload_bytes_total += len(idx) * self.source.sample_bytes
 
     @property
     def buffered_bytes(self) -> int:
@@ -120,9 +121,7 @@ class PrefetchingLoader:
         it = (self._buf[-1].iteration + 1) if self._buf else next_needed
         idx = self.indexer.indices(it, self.dp_rank, self.active_dp)
         self._buf.append(BufferedBatch(it, self.source.fetch(idx)))
-        nbytes = len(idx) * self.source.sample_bytes
-        self.preload_bytes_total += nbytes
-        return nbytes
+        return len(idx) * self.source.sample_bytes
 
     def repartition(self, active_dp: int, dp_rank: Optional[int] = None
                     ) -> None:

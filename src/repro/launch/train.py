@@ -74,6 +74,7 @@ def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
 
     from repro.configs import get_arch, reduce_for_smoke
     from repro.launch.cache import use_compile_cache
+    from repro.launch.spans import summary
     from repro.core.lccl import edge_key
     from repro.optim import AdamWConfig
     from repro.runtime.cluster import (ClusterConfig, FabricConfig,
@@ -103,6 +104,7 @@ def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
         recovery=args.recovery_policy)
 
     t0 = time.time()
+    t_run = time.perf_counter()
     run = TrainRun(clu, [], [])
     for step in range(args.steps):
         if args.inject_failure is not None and step == args.inject_failure:
@@ -161,6 +163,15 @@ def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
             moved = sum(clu.topology.edge(*e).n_finished for e in edges)
             print(f"  tier {tier}: {len(edges)} edges, "
                   f"{moved} transfers completed")
+    # where the host's time went, span by span (docs/tracing.md)
+    spent = summary(since=t_run)
+    steps = spent.get("loop.step", {}).get("calls", 0) or 1
+    print(f"host seconds per step by span ({steps} steps):")
+    for name, row in sorted(spent.items(), key=lambda kv: -kv[1]["seconds"]):
+        extra = "".join(f", {k} {v / steps:.6g}" for k, v in row.items()
+                        if k not in ("seconds", "calls"))
+        print(f"  {name:<20} {row['seconds'] / steps:.4f} s "
+              f"({row['calls']} calls{extra})")
     return run
 
 

@@ -39,6 +39,7 @@ from repro.core.lccl import (Edge, LinkTopology, PodFabric, StormReport,
                              edge_key, inject_storm)
 from repro.data.indexer import TidIndexer
 from repro.data.loader import PrefetchingLoader, SyntheticTokens
+from repro.launch.spans import span
 from repro.models import build_model
 from repro.optim import AdamWConfig, adamw_update, cast_params, cosine_schedule
 # recovery machinery lives in runtime/recovery.py; the vector/shard helpers
@@ -137,14 +138,21 @@ def loop_step(model, hp: AdamWConfig) -> Callable:
     on one device. The state is donated: the step updates it in place, so
     at published width one copy of the state (not two) is resident."""
 
+    def forward(p, batch):
+        with jax.named_scope("forward"):
+            return model.loss(p, batch)
+
     def step(state, batch):
+        # the backward's ops carry the forward's scope under `transpose`
         (loss, aux), grads = jax.value_and_grad(
-            lambda p: model.loss(p, batch), has_aux=True)(state["params"])
-        lr = cosine_schedule(state["step"], lr=hp.lr,
-                             warmup_steps=hp.warmup_steps,
-                             total_steps=hp.total_steps)
-        _, new_opt = adamw_update(grads, state["opt"], state["step"], hp, lr)
-        new_params = cast_params(new_opt["master"], state["params"])
+            forward, has_aux=True)(state["params"], batch)
+        with jax.named_scope("optimizer"):
+            lr = cosine_schedule(state["step"], lr=hp.lr,
+                                 warmup_steps=hp.warmup_steps,
+                                 total_steps=hp.total_steps)
+            _, new_opt = adamw_update(grads, state["opt"], state["step"], hp,
+                                      lr)
+            new_params = cast_params(new_opt["master"], state["params"])
         return ({"step": state["step"] + 1, "params": new_params,
                  "opt": new_opt}, loss)
 
@@ -268,6 +276,7 @@ class SimCluster:
         # recovery still pending (resume-after-rescale)
         self._layout: Optional[Dict[str, Any]] = None
         self._lazy_done_at: Optional[int] = None
+        self.recoveries = 0            # recover() calls: its spans' id
         self.loss_history: List[float] = []
         # --- self-driving reliability loop (runtime/reliability.py) --- #
         # per-worker slowdown multipliers (scenario-injected stragglers)
@@ -357,21 +366,22 @@ class SimCluster:
         """Instant checkpoint: split unique opt state into dp shards; worker
         (i+1) stores worker i's shard (the in-step ppermute, host view) AND
         streams it as chunked STATE traffic over its adjacent fabric edge."""
-        vec, meta = _flatten_opt(self.state["opt"])
-        self._opt_meta = meta
-        slices = shard_slices(len(vec), self.dp)
-        it = self.iteration
-        active = self.active_dp
-        # views, not copies: `vec` is this step's own buffer and nothing
-        # writes to a held snapshot
-        shards = {i: vec[slices[i]] for i in range(active)}
-        for i, w in enumerate(self.workers[:active]):
-            # predecessor's shard lands in this worker's host RAM
-            nbr_shard = ({"shard": shards[(i - 1) % active]}
-                         if (w.alive and w.host_alive) else None)
-            w.engine.on_step(it, {"shard": shards[i]}, nbr_shard,
-                             t=self.sim_time)
-            self.controller.report_ckpt(i, it)
+        with span("ckpt.instant"):
+            vec, meta = _flatten_opt(self.state["opt"])
+            self._opt_meta = meta
+            slices = shard_slices(len(vec), self.dp)
+            it = self.iteration
+            active = self.active_dp
+            # views, not copies: `vec` is this step's own buffer and nothing
+            # writes to a held snapshot
+            shards = {i: vec[slices[i]] for i in range(active)}
+            for i, w in enumerate(self.workers[:active]):
+                # predecessor's shard lands in this worker's host RAM
+                nbr_shard = ({"shard": shards[(i - 1) % active]}
+                             if (w.alive and w.host_alive) else None)
+                w.engine.on_step(it, {"shard": shards[i]}, nbr_shard,
+                                 t=self.sim_time)
+                self.controller.report_ckpt(i, it)
 
     def step_traffic_profile(self):
         """This step's wire volumes (train/step.py accounting). On a pod
@@ -389,70 +399,73 @@ class SimCluster:
         return step_traffic(self._grad_bytes, self.active_dp)
 
     def step(self) -> float:
-        batch = self._assemble_batch()
-        # the allreduce volume for this step goes on EVERY live ring edge
-        # (per-edge TRAIN), preempting any in-flight STATE chunks there
-        submit_step_traffic(self.transport, self.step_traffic_profile(),
-                            self.sim_time)
-        self.state, loss = self._step(self.state, batch)
-        jax.block_until_ready(loss)
-        self.iteration += 1
-        self._shard_and_backup()
-        # per-worker MODELED durations (sim seconds, never wall time): the
-        # synchronous step paces at the slowest worker, so an injected
-        # straggler stretches everyone's iteration — exactly what the
-        # reliability loop's EWMAs watch for
-        step_times: Dict[int, float] = {}
-        for w in self.workers[:self.active_dp]:
-            w.engine.maybe_full_checkpoint(
-                self.iteration, self.state if w.wid == 0 else
-                {"marker": np.zeros(1)}, t=self.sim_time)
-            dt_w = self.t_iter_model * self._slow_factor.get(w.wid, 1.0)
-            step_times[w.wid] = dt_w
-            w.step_times.append(dt_w)
-        # advance the link model one modeled iteration in a single window:
-        # the fabric clock is event-ordered, so a cross-pod (multi-hop)
-        # instant stream lands at its exact store-and-forward instant inside
-        # the iteration it was submitted in. Instant-ckpt chunks that drain
-        # before the boundary were hidden (the FCR condition, emergent from
-        # the transport instead of Eq. 2) — tracked globally and per
-        # delivering fabric edge
-        dt = max(step_times.values()) if step_times else self.t_iter_model
-        self.sim_time += dt
-        # live workers heartbeat ON THE SIM CLOCK at the step boundary — a
-        # dead worker's slot freezes and the liveness scan finds it
-        for w in self.workers[:self.active_dp]:
-            if w.alive:
-                self.controller.beat(w.wid, now=self.sim_time)
-        self.last_step_times = step_times
-        self.transport.run(until=self.sim_time)
-        tickets = []
-        for w in self.workers[:self.active_dp]:
-            tk = w.engine.last_instant_ticket
-            if tk is None:
-                continue
-            tickets.append(tk)
-            # book the verdict on the fabric edge that DELIVERED the shard —
-            # the last hop of the path the stream actually rode. On a pod
-            # fabric, consecutive wids across a pod boundary have no direct
-            # edge, so the raw (src, dst) pair would be a phantom key
-            # invisible to per-edge summaries
-            e = tk.delivery_edge
-            if e is None:              # single-node fabric: local delivery
-                src, dst = self.transport.instant_route(w.wid)
-                e = edge_key(src, dst)
-            book = (self.edge_instant_hidden if tk.complete
-                    else self.edge_instant_exposed)
-            book[e] = book.get(e, 0) + 1
-        if tickets:
-            if all(tk.complete for tk in tickets):
-                self.instant_hidden += 1
-            else:
-                self.instant_exposed += 1
-                self.exposed_seconds += dt
-        self.reliability.tick(self.sim_time)
-        self.loss_history.append(float(loss))
-        return float(loss)
+        with span("loop.step", iteration=self.iteration):
+            batch = self._assemble_batch()
+            # the allreduce volume for this step goes on EVERY live ring edge
+            # (per-edge TRAIN), preempting any in-flight STATE chunks there
+            submit_step_traffic(self.transport, self.step_traffic_profile(),
+                                self.sim_time)
+            self.state, loss = self._step(self.state, batch)
+            with span("loop.device_wait"):
+                jax.block_until_ready(loss)
+            self.iteration += 1
+            self._shard_and_backup()
+            # per-worker MODELED durations (sim seconds, never wall time):
+            # the synchronous step paces at the slowest worker, so an injected
+            # straggler stretches everyone's iteration — exactly what the
+            # reliability loop's EWMAs watch for
+            step_times: Dict[int, float] = {}
+            for w in self.workers[:self.active_dp]:
+                w.engine.maybe_full_checkpoint(
+                    self.iteration, self.state if w.wid == 0 else
+                    {"marker": np.zeros(1)}, t=self.sim_time)
+                dt_w = self.t_iter_model * self._slow_factor.get(w.wid, 1.0)
+                step_times[w.wid] = dt_w
+                w.step_times.append(dt_w)
+            # advance the link model one modeled iteration in a single
+            # window: the fabric clock is event-ordered, so a cross-pod
+            # (multi-hop) instant stream lands at its exact store-and-forward
+            # instant inside the iteration it was submitted in. Instant-ckpt
+            # chunks that drain before the boundary were hidden (the FCR
+            # condition, emergent from the transport instead of Eq. 2) —
+            # tracked globally and per delivering fabric edge
+            dt = max(step_times.values()) if step_times else self.t_iter_model
+            self.sim_time += dt
+            # live workers heartbeat ON THE SIM CLOCK at the step boundary —
+            # a dead worker's slot freezes and the liveness scan finds it
+            for w in self.workers[:self.active_dp]:
+                if w.alive:
+                    self.controller.beat(w.wid, now=self.sim_time)
+            self.last_step_times = step_times
+            with span("fabric.run"):
+                self.transport.run(until=self.sim_time)
+            tickets = []
+            for w in self.workers[:self.active_dp]:
+                tk = w.engine.last_instant_ticket
+                if tk is None:
+                    continue
+                tickets.append(tk)
+                # book the verdict on the fabric edge that DELIVERED the
+                # shard — the last hop of the path the stream actually rode.
+                # On a pod fabric, consecutive wids across a pod boundary
+                # have no direct edge, so the raw (src, dst) pair would be a
+                # phantom key invisible to per-edge summaries
+                e = tk.delivery_edge
+                if e is None:              # single-node fabric: local delivery
+                    src, dst = self.transport.instant_route(w.wid)
+                    e = edge_key(src, dst)
+                book = (self.edge_instant_hidden if tk.complete
+                        else self.edge_instant_exposed)
+                book[e] = book.get(e, 0) + 1
+            if tickets:
+                if all(tk.complete for tk in tickets):
+                    self.instant_hidden += 1
+                else:
+                    self.instant_exposed += 1
+                    self.exposed_seconds += dt
+            self.reliability.tick(self.sim_time)
+            self.loss_history.append(float(loss))
+            return float(loss)
 
     def run(self, n_steps: int) -> List[float]:
         return [self.step() for _ in range(n_steps)]
@@ -603,63 +616,68 @@ class SimCluster:
             base = faults or FaultScript()
             faults = dataclasses.replace(base, **legacy)
         faults = faults or FaultScript()
-        pol = resolve_policy(policy) if policy is not None \
-            else self.recovery_policy
-        failed = [w.wid for w in self.workers if not w.alive]
-        assert failed, "no failed workers"
-        # replacement pods come up before state moves: their ring edges
-        # relight, while any OTHER dark node keeps its edges dark and
-        # recovery paths route around it
-        for wid in failed:
-            self.topology.restore_node(wid)
-        timeline = orchestration_timeline(self, faults)
-
-        # lazy backup: healthy DP rank 0 persists redundant state (params).
-        # It goes on the wire NOW, overlapping the detection/pod-creation
-        # window (§4.2) — recovery chunks only start once pods are up, so
-        # the lazy stream has the link to itself first
-        rank0 = self.workers[0]
-        if rank0.alive and self._lazy_done_at != self.iteration:
-            # once per iteration: a resumed recovery must not re-save and
-            # re-stream the multi-GB redundant state it already persisted
-            rank0.engine.lazy_backup(self.iteration,
-                                     {"params": self.state["params"]},
-                                     is_dp_rank0=True, t=self.sim_time)
-            self._lazy_done_at = self.iteration
-        t_orch = sum(timeline.values())
-        if self._detection_elapsed:
-            # the reliability loop detected this breakdown ON the sim clock
-            # (advance_idle windows) — the detection leg already elapsed, so
-            # the streams must not wait through it a second time. The
-            # timeline still reports it (measured): it is part of the
-            # failover the job experienced.
-            t_orch -= timeline.get("detection", 0.0)
-
-        plan = pol.plan(self, failed, faults, timeline=timeline,
-                        t_start=self.sim_time + t_orch)
-        report = pol.execute(plan)
-        if report.kind == "interrupted":
-            # workers stay down; their edges go dark again
+        self.recoveries += 1
+        with span("recover", recovery=self.recoveries):
+            pol = resolve_policy(policy) if policy is not None \
+                else self.recovery_policy
+            failed = [w.wid for w in self.workers if not w.alive]
+            assert failed, "no failed workers"
+            # replacement pods come up before state moves: their ring edges
+            # relight, while any OTHER dark node keeps its edges dark and
+            # recovery paths route around it
             for wid in failed:
-                self.topology.fail_node(wid)
-            return report              # partial chunks retained
+                self.topology.restore_node(wid)
+            timeline = orchestration_timeline(self, faults)
 
-        for wid in failed:
-            self.workers[wid].alive = True
-            self.workers[wid].host_alive = True
-            self.controller.beat(wid, now=self.sim_time)
-            self.workers[wid].loader.repartition(self.active_dp)
-        self.reliability.on_recovered(failed)
-        self._measured_detection = None
-        self._detection_elapsed = False
-        # a completed recovery repairs the storm's fabric damage along with
-        # the pods: the recovery STREAMS had to race around the dark edges
-        # (DCN detours), but the healed job trains on a whole fabric again
-        if self.last_storm is not None:
-            for e in self.last_storm.edges:
-                self.topology.restore_edge(*e)
-            self.last_storm = None
-        return report
+            # lazy backup: healthy DP rank 0 persists redundant state
+            # (params). It goes on the wire NOW, overlapping the
+            # detection/pod-creation window (§4.2) — recovery chunks only
+            # start once pods are up, so the lazy stream has the link to
+            # itself first
+            rank0 = self.workers[0]
+            if rank0.alive and self._lazy_done_at != self.iteration:
+                # once per iteration: a resumed recovery must not re-save and
+                # re-stream the multi-GB redundant state it already persisted
+                with span("recover.lazy_backup"):
+                    rank0.engine.lazy_backup(
+                        self.iteration, {"params": self.state["params"]},
+                        is_dp_rank0=True, t=self.sim_time)
+                self._lazy_done_at = self.iteration
+            t_orch = sum(timeline.values())
+            if self._detection_elapsed:
+                # the reliability loop detected this breakdown ON the sim
+                # clock (advance_idle windows) — the detection leg already
+                # elapsed, so the streams must not wait through it a second
+                # time. The timeline still reports it (measured): it is part
+                # of the failover the job experienced.
+                t_orch -= timeline.get("detection", 0.0)
+
+            plan = pol.plan(self, failed, faults, timeline=timeline,
+                            t_start=self.sim_time + t_orch)
+            report = pol.execute(plan)
+            if report.kind == "interrupted":
+                # workers stay down; their edges go dark again
+                for wid in failed:
+                    self.topology.fail_node(wid)
+                return report              # partial chunks retained
+
+            for wid in failed:
+                self.workers[wid].alive = True
+                self.workers[wid].host_alive = True
+                self.controller.beat(wid, now=self.sim_time)
+                self.workers[wid].loader.repartition(self.active_dp)
+            self.reliability.on_recovered(failed)
+            self._measured_detection = None
+            self._detection_elapsed = False
+            # a completed recovery repairs the storm's fabric damage along
+            # with the pods: the recovery STREAMS had to race around the dark
+            # edges (DCN detours), but the healed job trains on a whole
+            # fabric again
+            if self.last_storm is not None:
+                for e in self.last_storm.edges:
+                    self.topology.restore_edge(*e)
+                self.last_storm = None
+            return report
 
     # ------------------------------------------------------------------ #
     # Elastic rescale (no spare capacity): shrink DP, repartition data

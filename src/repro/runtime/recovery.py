@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.ckpt.stream import ChunkedStream, StreamAssembler
+from repro.launch.spans import count, span
 from repro.train.step import ReplayCost, ReplayCostModel, replay_compute_cost
 
 PyTree = Any
@@ -49,13 +50,15 @@ PyTree = Any
 def _flatten_opt(opt: PyTree) -> Tuple[np.ndarray, Any]:
     leaves, treedef = jax.tree_util.tree_flatten(opt)
     shapes = [(l.shape, l.dtype) for l in leaves]
-    # filled leaf by leaf, with no concatenated temporaries
-    vec = np.empty(sum(int(np.prod(s)) for s, _ in shapes), np.float32)
-    off = 0
-    for l in leaves:
-        n = int(np.prod(l.shape))
-        vec[off:off + n] = np.asarray(l).ravel()
-        off += n
+    with span("opt.d2h"):
+        # filled leaf by leaf, with no concatenated temporaries
+        vec = np.empty(sum(int(np.prod(s)) for s, _ in shapes), np.float32)
+        off = 0
+        for l in leaves:
+            n = int(np.prod(l.shape))
+            vec[off:off + n] = np.asarray(l).ravel()
+            off += n
+        count("bytes", vec.nbytes)
     return vec, (treedef, shapes)
 
 
@@ -538,52 +541,54 @@ def _execute_neighbor_streams(plan: RecoveryPlan, stream_wids: List[int],
     # ---- move the failed workers' shards as chunked STATE traffic ----
     # each stream rides the shortest LIVE edge path holder -> newcomer:
     # adjacent edge normally, multi-hop around dark nodes/edges otherwise
-    t0 = plan.t_start
-    chunks_total = chunks_sent = chunks_reused = 0
-    tickets, inflight = [], {}
-    budget = faults.interrupt_after_chunks
-    corrupt_left = faults.corrupt_chunks
-    interrupted = False
-    for wid in sorted(stream_wids):
-        holder_wid = new_of[(old_of[wid] + 1) % ldp]
-        holder = cluster.workers[holder_wid]
-        key = (wid, target)
-        if key in cluster._pending_recovery:
-            stream, asm = cluster._pending_recovery[key]
-            chunks_reused += asm.received
-        else:
-            stream = holder.engine.export_stream(target, which="neighbor")
-            asm = StreamAssembler.for_stream(stream)
-            cluster._pending_recovery[key] = (stream, asm)
-        chunks_total += stream.n_chunks
-        missing = asm.missing()
-        take = missing
-        if budget is not None:
-            take = missing[:max(budget - chunks_sent, 0)]
-            if len(take) < len(missing):
-                interrupted = True
-        # wire corruption: the CRC rejects these on delivery and the
-        # NACK path retransmits each one immediately
-        for seq in take[:corrupt_left]:
-            cluster.transport.corrupt_once(stream.stream_id, seq)
-        corrupt_left -= min(corrupt_left, len(take))
-        if take:
-            tickets.append(cluster.transport.send(
-                stream, t0, assembler=asm, seqs=take,
-                src=holder_wid, dst=wid, k=plan.route_k))
-            chunks_sent += len(take)
-        inflight[wid] = (stream, asm)
-    if faults.mid_stream_degrade is not None and tickets:
-        # a gray link browns out UNDER the in-flight streams: run the
-        # fabric to the degrade instant, apply it (epoch bump), and let the
-        # drain's entry check re-balance the not-yet-started chunks over
-        # the surviving paths' residual capacity
-        u, v, factor = faults.mid_stream_degrade
-        cluster.transport.run(until=t0 + max(float(faults.degrade_at_s),
-                                             0.0))
-        cluster.degrade_edge(int(u), int(v), float(factor))
-    cluster.transport.drain()
-    bytes_streamed = cluster.transport.accounting()["state_bytes"] - acct0
+    with span("recover.stream"):
+        t0 = plan.t_start
+        chunks_total = chunks_sent = chunks_reused = 0
+        tickets, inflight = [], {}
+        budget = faults.interrupt_after_chunks
+        corrupt_left = faults.corrupt_chunks
+        interrupted = False
+        for wid in sorted(stream_wids):
+            holder_wid = new_of[(old_of[wid] + 1) % ldp]
+            holder = cluster.workers[holder_wid]
+            key = (wid, target)
+            if key in cluster._pending_recovery:
+                stream, asm = cluster._pending_recovery[key]
+                chunks_reused += asm.received
+            else:
+                stream = holder.engine.export_stream(target, which="neighbor")
+                asm = StreamAssembler.for_stream(stream)
+                cluster._pending_recovery[key] = (stream, asm)
+            chunks_total += stream.n_chunks
+            missing = asm.missing()
+            take = missing
+            if budget is not None:
+                take = missing[:max(budget - chunks_sent, 0)]
+                if len(take) < len(missing):
+                    interrupted = True
+            # wire corruption: the CRC rejects these on delivery and the
+            # NACK path retransmits each one immediately
+            for seq in take[:corrupt_left]:
+                cluster.transport.corrupt_once(stream.stream_id, seq)
+            corrupt_left -= min(corrupt_left, len(take))
+            if take:
+                tickets.append(cluster.transport.send(
+                    stream, t0, assembler=asm, seqs=take,
+                    src=holder_wid, dst=wid, k=plan.route_k))
+                chunks_sent += len(take)
+            inflight[wid] = (stream, asm)
+        if faults.mid_stream_degrade is not None and tickets:
+            # a gray link browns out UNDER the in-flight streams: run the
+            # fabric to the degrade instant, apply it (epoch bump), and let
+            # the drain's entry check re-balance the not-yet-started chunks
+            # over the surviving paths' residual capacity
+            u, v, factor = faults.mid_stream_degrade
+            cluster.transport.run(until=t0 + max(float(faults.degrade_at_s),
+                                                 0.0))
+            cluster.degrade_edge(int(u), int(v), float(factor))
+        cluster.transport.drain()
+        bytes_streamed = cluster.transport.accounting()["state_bytes"] - acct0
+        count("bytes", bytes_streamed)
 
     if interrupted:
         # the second failure struck mid-transfer: time (and the link
@@ -607,41 +612,44 @@ def _execute_neighbor_streams(plan: RecoveryPlan, stream_wids: List[int],
     # slice of the SNAPSHOT layout (which differs from the live
     # numbering only across an elastic shrink) ----
     vec, meta = _flatten_opt(cluster.state["opt"])
-    slices = shard_slices(len(vec), ldp)
-    for o in range(ldp):
-        owner = new_of.get(o)
-        if owner is not None and owner in inflight:
-            stream, asm = inflight[owner]
-            # NACK retransmission heals CRC rejects in-stream, so
-            # `rejected > 0` is fine as long as assembly completed
-            assert asm.complete, \
-                f"stream {stream.stream_id} incomplete"
-            vec[slices[o]] = asm.to_flat_dict()["shard"]
-            cluster._pending_recovery.pop((owner, target), None)
-        elif owner is not None and owner in compute_wids:
-            # replay leg: the replayers rebuild this slice at the current
-            # iteration — the simulator vector already holds the truth, so
-            # the slice stands as-is (zero fabric bytes moved for it)
-            continue
-        else:
-            kind, src_wid = cluster._slice_source(o, ldp, new_of)
-            keeper = (cluster.workers[src_wid].engine.own if kind == "own"
-                      else cluster.workers[src_wid].engine.neighbor)
-            snap = keeper.get(target)
-            assert snap is not None, \
-                f"version {target} missing for layout slice {o}"
-            vec[slices[o]] = snap.state["shard"]
+    with span("recover.stream"):
+        slices = shard_slices(len(vec), ldp)
+        for o in range(ldp):
+            owner = new_of.get(o)
+            if owner is not None and owner in inflight:
+                stream, asm = inflight[owner]
+                # NACK retransmission heals CRC rejects in-stream, so
+                # `rejected > 0` is fine as long as assembly completed
+                assert asm.complete, \
+                    f"stream {stream.stream_id} incomplete"
+                vec[slices[o]] = asm.to_flat_dict()["shard"]
+                cluster._pending_recovery.pop((owner, target), None)
+            elif owner is not None and owner in compute_wids:
+                # replay leg: the replayers rebuild this slice at the
+                # current iteration — the simulator vector already holds the
+                # truth, so the slice stands as-is (zero fabric bytes moved)
+                continue
+            else:
+                kind, src_wid = cluster._slice_source(o, ldp, new_of)
+                eng = cluster.workers[src_wid].engine
+                keeper = eng.own if kind == "own" else eng.neighbor
+                snap = keeper.get(target)
+                assert snap is not None, \
+                    f"version {target} missing for layout slice {o}"
+                vec[slices[o]] = snap.state["shard"]
     cluster._layout = None         # live numbering is authoritative again
     param_dtypes = jax.tree.map(lambda p: p.dtype, cluster.state["params"])
     # the host vector holds everything the restore needs: release the old
     # state's device buffers first, or two states share one chip's memory
     cluster.state = None
-    opt = jax.tree.map(jnp.asarray, _unflatten_opt(vec, meta))
-    # a copy, never an alias of the master (the step donates both)
-    params = jax.tree.map(lambda m, dt: jnp.array(m, dt), opt["master"],
-                          param_dtypes)
-    cluster.state = {"step": jnp.asarray(target, jnp.int32),
-                     "params": params, "opt": opt}
+    with span("recover.upload"):
+        opt = jax.tree.map(jnp.asarray, _unflatten_opt(vec, meta))
+        # a copy, never an alias of the master (the step donates both)
+        params = jax.tree.map(lambda m, dt: jnp.array(m, dt), opt["master"],
+                              param_dtypes)
+        cluster.state = {"step": jnp.asarray(target, jnp.int32),
+                         "params": params, "opt": opt}
+        count("bytes", vec.nbytes)
     cluster.iteration = target
 
     # timeline: network recovery overlaps state loading (§5.2); the
@@ -704,7 +712,8 @@ def _execute_full(plan: RecoveryPlan) -> RecoveryReport:
         chunks_total = stream.n_chunks
 
     cluster.state = None           # release the old state's device buffers
-    cluster.state = jax.tree.map(jnp.asarray, restored)
+    with span("recover.upload"):
+        cluster.state = jax.tree.map(jnp.asarray, restored)
     rolled = cluster.iteration - it
     cluster.iteration = it
     full_bytes = sum(np.asarray(l).nbytes

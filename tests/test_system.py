@@ -31,6 +31,10 @@ def test_train_cli_with_failover():
                 "--inject-failure", "4"])
     assert "recovered from neighbor" in out
     assert "done:" in out
+    # the run ends with host seconds per step by span
+    assert "host seconds per step by span (8 steps):" in out
+    for name in ("loop.step", "ckpt.instant", "opt.d2h", "recover.upload"):
+        assert f"\n  {name} " in out, name
 
 
 def test_serve_cli():
