@@ -44,7 +44,8 @@ from repro.models import build_model
 from repro.optim import AdamWConfig, adamw_update, cast_params, cosine_schedule
 # recovery machinery lives in runtime/recovery.py; the vector/shard helpers
 # and RecoveryReport are re-exported here for back-compat imports
-from repro.runtime.recovery import (FaultScript, RecoveryError, RecoveryPlan,
+from repro.runtime.recovery import (FaultScript, HostVectorPool,
+                                    RecoveryError, RecoveryPlan,
                                     RecoveryPolicy, RecoveryReport,
                                     StreamRecovery, _flatten_opt,
                                     _unflatten_opt, orchestration_timeline,
@@ -267,6 +268,8 @@ class SimCluster:
         ]
         self._step = loop_step(self.model, self.hp)
         self._opt_meta = None
+        # the host vectors the optimizer state lands in, reused step to step
+        self.host_vectors = HostVectorPool()
         self._grad_bytes: Optional[float] = None
         # partial recovery transfers, keyed (failed_wid, target_iteration)
         self._pending_recovery: Dict[Tuple[int, int],
@@ -367,13 +370,13 @@ class SimCluster:
         (i+1) stores worker i's shard (the in-step ppermute, host view) AND
         streams it as chunked STATE traffic over its adjacent fabric edge."""
         with span("ckpt.instant"):
-            vec, meta = _flatten_opt(self.state["opt"])
+            vec, meta = _flatten_opt(self.state["opt"], self.host_vectors)
             self._opt_meta = meta
             slices = shard_slices(len(vec), self.dp)
             it = self.iteration
             active = self.active_dp
-            # views, not copies: `vec` is this step's own buffer and nothing
-            # writes to a held snapshot
+            # views, not copies: the pool refills `vec` only once no
+            # snapshot, stream or chunk refers to it
             shards = {i: vec[slices[i]] for i in range(active)}
             for i, w in enumerate(self.workers[:active]):
                 # predecessor's shard lands in this worker's host RAM

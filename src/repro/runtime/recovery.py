@@ -29,6 +29,7 @@ plumbing); `runtime/cluster.py` re-exports them for back-compat.
 from __future__ import annotations
 
 import dataclasses
+import sys
 from dataclasses import dataclass, field
 from typing import (Any, ClassVar, Dict, List, Optional, Protocol, Sequence,
                     Tuple, Union, runtime_checkable)
@@ -47,18 +48,81 @@ PyTree = Any
 # --------------------------------------------------------------------------- #
 # Optimizer-vector plumbing (moved from runtime/cluster.py)
 # --------------------------------------------------------------------------- #
-def _flatten_opt(opt: PyTree) -> Tuple[np.ndarray, Any]:
+def _refs(vecs: List[np.ndarray], i: int) -> int:
+    return sys.getrefcount(vecs[i])
+
+
+# what `_refs` reads for a vector that only its pool's list refers to
+_POOL_ONLY = _refs([np.empty(0, np.float32)], 0)
+
+
+class HostVectorPool:
+    """The flat float32 vectors `_flatten_opt` lands the optimizer state in,
+    handed out again once nothing but the pool refers to them.
+
+    Whatever still reads a vector holds a reference to it: a keeper's
+    snapshot and the chunks of a stream cut from one (in flight, stale, or a
+    pending recovery's) are views of it, and an upload to the device holds
+    the array it reads until the transfer is done. A vector with no such
+    reference is free. Host memory already mapped is written again instead
+    of a fresh vector being faulted in; at most one free vector of each
+    length is kept, so the peak is what a fresh vector a step would take.
+    In a steady loop the pool holds three: the keepers' two versions and the
+    one they dropped last, which the next step refills."""
+
+    def __init__(self) -> None:
+        self._vecs: List[np.ndarray] = []
+
+    def take(self, n: int) -> Tuple[np.ndarray, bool]:
+        """A float32 vector of length `n`, and whether it was reused."""
+        free = {i for i in range(len(self._vecs))
+                if _refs(self._vecs, i) <= _POOL_ONLY}
+        kept, free_lens, vec = [], set(), None
+        for i, v in enumerate(self._vecs):
+            if i in free:
+                if len(v) == n and vec is None:
+                    vec = v
+                elif len(v) == n or len(v) in free_lens:
+                    continue               # a second free one: let it go
+                free_lens.add(len(v))
+            kept.append(v)
+        self._vecs = kept
+        if vec is not None:
+            return vec, True
+        vec = np.empty(n, np.float32)
+        self._vecs.append(vec)
+        return vec, False
+
+
+def _flatten_opt(opt: PyTree, pool: HostVectorPool
+                 ) -> Tuple[np.ndarray, Any]:
+    """The optimizer state as one float32 vector on the host, in leaf order,
+    and the meta `_unflatten_opt` rebuilds it from. The vector is one that
+    `pool` hands out (counter `reused_bytes` when it was reused). Each
+    leaf is written into its place while the next one's copy to the host is
+    in flight. One copy at a time, as a plain read-back moves them: with
+    every leaf in flight at once the copies ran slower on a TPU v5e and
+    the host's peak memory rose."""
     leaves, treedef = jax.tree_util.tree_flatten(opt)
     shapes = [(l.shape, l.dtype) for l in leaves]
+    n = sum(int(np.prod(s)) for s, _ in shapes)
+
+    def start(i: int) -> None:
+        if i < len(leaves) and isinstance(leaves[i], jax.Array):
+            leaves[i].copy_to_host_async()
     with span("opt.d2h"):
+        vec, reused = pool.take(n)
+        start(0)
         # filled leaf by leaf, with no concatenated temporaries
-        vec = np.empty(sum(int(np.prod(s)) for s, _ in shapes), np.float32)
         off = 0
-        for l in leaves:
-            n = int(np.prod(l.shape))
-            vec[off:off + n] = np.asarray(l).ravel()
-            off += n
+        for i, l in enumerate(leaves):
+            host = np.asarray(l)           # leaf i has landed
+            start(i + 1)
+            k = int(np.prod(l.shape))
+            vec[off:off + k] = host.ravel()
+            off += k
         count("bytes", vec.nbytes)
+        count("reused_bytes", vec.nbytes if reused else 0)
     return vec, (treedef, shapes)
 
 
@@ -611,7 +675,7 @@ def _execute_neighbor_streams(plan: RecoveryPlan, stream_wids: List[int],
     # ---- every stream landed: rebuild the optimizer vector, slice by
     # slice of the SNAPSHOT layout (which differs from the live
     # numbering only across an elastic shrink) ----
-    vec, meta = _flatten_opt(cluster.state["opt"])
+    vec, meta = _flatten_opt(cluster.state["opt"], cluster.host_vectors)
     with span("recover.stream"):
         slices = shard_slices(len(vec), ldp)
         for o in range(ldp):

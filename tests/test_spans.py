@@ -114,7 +114,7 @@ def test_a_step_and_a_recovery_give_the_span_tree(tmp_path):
     inside = _children(recorded, ckpt)
     assert [s.name for s in inside] == ["opt.d2h"] + [
         "stream.chunk", "stream.send"] * 2
-    assert inside[0].counts == {"bytes": 12 * n_params}
+    assert inside[0].counts == {"bytes": 12 * n_params, "reused_bytes": 0}
     assert sum(s.counts["bytes"] for s in inside
                if s.name == "stream.chunk") == 12 * n_params
     assert all(s.ids == {"iteration": 0} for s in inside)
@@ -125,7 +125,8 @@ def test_a_step_and_a_recovery_give_the_span_tree(tmp_path):
     assert phases == ["recover.lazy_backup", "recover.stream", "opt.d2h",
                       "recover.stream", "recover.upload"]
     by = {s.name: s for s in _children(recorded, rec)}
-    assert by["opt.d2h"].counts == {"bytes": 12 * n_params}
+    assert by["opt.d2h"].counts == {"bytes": 12 * n_params,
+                                    "reused_bytes": 0}
     assert by["recover.upload"].counts == {"bytes": 12 * n_params}
     lazy = [s.name for s in _children(recorded, by["recover.lazy_backup"])]
     assert lazy == ["storage.save", "stream.chunk", "stream.send"]
