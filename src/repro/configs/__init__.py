@@ -34,13 +34,30 @@ class ArchConfig:
     use_qk_norm: bool = False
     tie_embeddings: bool = False
     rope_theta: float = 10_000.0
+    # --- YaRN rotary positions (DeepSeek-V2); yarn_factor 0 -> plain rope ---
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 0
+    yarn_mscale_all_dim: float = 0.0  # softmax scale x mscale^2
+    # --- multi-head latent attention (DeepSeek-V2); kv_lora_rank 0 -> off ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- MoE ---
     num_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0
     num_shared_experts: int = 0
     shared_expert_d_ff: int = 0
-    capacity_factor: float = 1.25
+    shared_expert_gate: bool = True   # Qwen2-MoE's sigmoid gate
+    norm_topk_prob: bool = True
+    # this chip's share of an expert-parallel layer: experts
+    # [expert_offset, expert_offset + experts_held); 0 -> every expert
+    experts_held: int = 0
+    expert_offset: int = 0
+    seq_aux: bool = False             # DeepSeek's sequence-wise balance loss
+    aux_loss_alpha: float = 0.01
+    first_k_dense: int = 0            # leading dense blocks before the MoE
     # --- SSM (Mamba2 / SSD) ---
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -75,9 +92,18 @@ class ArchConfig:
 
     @property
     def padded_experts(self) -> int:
-        """Experts padded to a multiple of 16 so EP-16 shards evenly; pads are
-        masked to -inf in the router."""
+        """Experts padded to a multiple of 16 so EP-16 shards an uncut
+        layer's expert stack evenly; the router never picks a pad."""
         return _round_up(self.num_experts, 16) if self.num_experts else 0
+
+    @property
+    def held_experts(self) -> int:
+        """Experts whose weights this layer holds."""
+        return self.experts_held or self.padded_experts
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @property
     def ssm_inner(self) -> int:
@@ -156,6 +182,7 @@ _MODULES = (
     "qwen2_moe_a2_7b",
     "internvl2_26b",
     "paper_workloads",
+    "deepseek_v2_lite",
 )
 
 

@@ -1,6 +1,6 @@
 """Qwen1.5-MoE-A2.7B — 60 routed experts top-4 + 4 shared experts
-[hf:Qwen/Qwen1.5-MoE-A2.7B; hf]. 60 experts are padded to 64 for EP-16 (router
-logits of pad experts masked to -inf; see ArchConfig.padded_experts)."""
+[hf:Qwen/Qwen1.5-MoE-A2.7B; hf]. The expert stack is padded from 60 to 64 for
+EP-16; the router scores the 60 (see ArchConfig.padded_experts)."""
 from repro.configs import ArchConfig, register
 
 register(ArchConfig(

@@ -17,7 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.layers import apply_rope, head_rms_norm
+from repro.models.layers import (apply_rope, head_rms_norm, rms_norm,
+                                 yarn_frequencies, yarn_mscale)
 
 _NEG_INF = -1e30
 
@@ -31,12 +32,14 @@ def repeat_kv(x: jax.Array, num_heads: int) -> jax.Array:
     return jnp.repeat(x, reps, axis=2)
 
 
-def dense_attention(q, k, v, *, causal: bool, q_offset: int = 0) -> jax.Array:
+def dense_attention(q, k, v, *, causal: bool, q_offset: int = 0,
+                    scale: Optional[float] = None) -> jax.Array:
     """Reference masked attention (materializes scores). Identical math to
     blockwise_attention; used in analysis mode and as the kernel oracle."""
     b, sq, h, hd = q.shape
     skv = k.shape[1]
-    scale = 1.0 / np.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / np.sqrt(hd)
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale,
                    k.astype(jnp.float32))
     if causal:
@@ -51,22 +54,25 @@ def dense_attention(q, k, v, *, causal: bool, q_offset: int = 0) -> jax.Array:
 def blockwise_attention(
     q: jax.Array,            # (B, Sq, H, hd)
     k: jax.Array,            # (B, Skv, H, hd)  (already repeated)
-    v: jax.Array,            # (B, Skv, H, hd)
+    v: jax.Array,            # (B, Skv, H, hd_v)
     *,
     causal: bool,
     q_offset: int = 0,       # absolute position of q[0] (prefill continuation)
     kv_block: int = 1024,
+    scale: Optional[float] = None,   # default 1 / sqrt(hd)
 ) -> jax.Array:
     """Online-softmax attention, scanning KV in blocks. fp32 accumulators.
+    v's head size may differ from q's and k's (latent attention).
 
     Analysis mode uses the dense masked form (identical FLOPs; its backward
     all-reduces make the reported collective term an UPPER BOUND on the
     production blockwise form — both measured, EXPERIMENTS.md §Perf)."""
     from repro.models.modes import in_analysis_mode
     if in_analysis_mode():
-        return dense_attention(q, k, v, causal=causal, q_offset=q_offset)
+        return dense_attention(q, k, v, causal=causal, q_offset=q_offset,
+                               scale=scale)
     b, sq, h, hd = q.shape
-    skv = k.shape[1]
+    skv, hv = k.shape[1], v.shape[-1]
     kv_block = min(kv_block, skv)
     n_blocks = (skv + kv_block - 1) // kv_block
     pad = n_blocks * kv_block - skv
@@ -74,10 +80,11 @@ def blockwise_attention(
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
 
-    scale = 1.0 / np.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / np.sqrt(hd)
     qf = (q.astype(jnp.float32) * scale).transpose(0, 2, 1, 3)  # (B,H,Sq,hd)
     kb = k.transpose(0, 2, 1, 3).reshape(b, h, n_blocks, kv_block, hd)
-    vb = v.transpose(0, 2, 1, 3).reshape(b, h, n_blocks, kv_block, hd)
+    vb = v.transpose(0, 2, 1, 3).reshape(b, h, n_blocks, kv_block, hv)
     q_pos = q_offset + jnp.arange(sq)
 
     def body(carry, inputs):
@@ -99,7 +106,7 @@ def blockwise_attention(
         return (acc, new_max, row_sum), None
 
     init = (
-        jnp.zeros((b, h, sq, hd), jnp.float32),
+        jnp.zeros((b, h, sq, hv), jnp.float32),
         jnp.full((b, h, sq), _NEG_INF, jnp.float32),
         jnp.zeros((b, h, sq), jnp.float32),
     )
@@ -154,6 +161,8 @@ def decode_attention(
 # Full attention sub-block (projections + rope + attention + out-proj)
 # --------------------------------------------------------------------------- #
 def attn_init(key, cfg, dtype) -> Dict:
+    if cfg.is_mla:
+        return mla_init(key, cfg, dtype)
     hd = cfg.resolved_head_dim
     h, kh, d = cfg.num_heads, cfg.num_kv_heads, cfg.d_model
     ks = jax.random.split(key, 4)
@@ -198,6 +207,8 @@ def _ulysses(q, k, v):
 
 def self_attention(p: Dict, cfg, x: jax.Array, *, causal: bool = True,
                    rope: bool = True, kv_block: int = 1024) -> jax.Array:
+    if cfg.is_mla:
+        return mla_attention(p, cfg, x, kv_block=kv_block)
     b, s, _ = x.shape
     positions = jnp.arange(s)
     q, k, v = _project_qkv(p, cfg, x, positions, rope=rope)
@@ -238,6 +249,63 @@ def self_attention_decode(p: Dict, cfg, x: jax.Array, cache: Tuple,
     out = decode_attention(q, k_cache, v_cache, index + 1, cfg.num_heads)
     out = out.reshape(b, 1, -1) @ p["wo"]
     return out, (k_cache, v_cache)
+
+
+# --------------------------------------------------------------------------- #
+# Multi-head latent attention (DeepSeek-V2), for training: the latent is
+# expanded to every head's K and V (not absorbed into the projections)
+# --------------------------------------------------------------------------- #
+def mla_init(key, cfg, dtype) -> Dict:
+    h, d, r = cfg.num_heads, cfg.d_model, cfg.kv_lora_rank
+    nope, rope, hv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+
+    def w(k, shape, fan_in):
+        return (jax.random.normal(k, shape) / np.sqrt(fan_in)).astype(dtype)
+    return {
+        "wq": w(ks[0], (d, h * (nope + rope)), d),    # per head [nope, rope]
+        "wkv_a": w(ks[1], (d, r + rope), d),          # [latent, shared rope key]
+        "kv_norm": jnp.zeros((r,), dtype),
+        "wkv_b": w(ks[2], (r, h * (nope + hv)), r),   # per head [k nope, v]
+        "wo": w(ks[3], (h * hv, d), h * hv),
+    }
+
+
+def mla_softmax_scale(cfg) -> float:
+    """1 / sqrt(q.k head size), times YaRN's mscale squared."""
+    m = yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+    return m * m / np.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+
+
+def mla_attention(p: Dict, cfg, x: jax.Array, *,
+                  kv_block: int = 1024) -> jax.Array:
+    """Causal self-attention of (B, S, D): queries of `nope + rope` per head,
+    keys of the RMS-normed latent's `nope` part per head beside one rotary
+    key shared by every head, values of `v_head_dim`."""
+    with jax.named_scope("mla"):
+        b, s, _ = x.shape
+        h, r = cfg.num_heads, cfg.kv_lora_rank
+        nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        positions = jnp.arange(s)
+        # beta_fast 32 and beta_slow 1: DeepSeek-V2's published rope_scaling
+        freqs = yarn_frequencies(
+            rope, cfg.rope_theta, cfg.yarn_factor,
+            cfg.yarn_original_max_position, 32.0,
+            1.0) if cfg.yarn_factor else None
+        q = (x @ p["wq"]).reshape(b, s, h, nope + rope)
+        kv_a = x @ p["wkv_a"]
+        kv = (rms_norm(kv_a[..., :r], p["kv_norm"]) @ p["wkv_b"]).reshape(
+            b, s, h, nope + cfg.v_head_dim)
+        q_pe = apply_rope(q[..., nope:], positions, cfg.rope_theta, freqs)
+        k_pe = apply_rope(kv_a[:, :, None, r:], positions, cfg.rope_theta,
+                          freqs)
+        q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_pe, (b, s, h, rope))], axis=-1)
+        out = blockwise_attention(q, k, kv[..., nope:], causal=True,
+                                  kv_block=kv_block,
+                                  scale=mla_softmax_scale(cfg))
+        return out.reshape(b, s, -1) @ p["wo"]
 
 
 # --------------------------------------------------------------------------- #

@@ -6,7 +6,7 @@ array's dtype (bf16 in production) with fp32 accumulation where it matters
 """
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -34,11 +34,38 @@ def rope_frequencies(head_dim: int, theta: float) -> np.ndarray:
     return 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding. x: (..., seq, heads, head_dim); positions: (..., seq)."""
+def yarn_frequencies(head_dim: int, theta: float, factor: float,
+                     original: int, beta_fast: float,
+                     beta_slow: float) -> np.ndarray:
+    """YaRN's rotary frequencies (DeepSeek-V2's `DeepseekV2YarnRotaryEmbedding`):
+    the plain frequencies above `beta_fast` rotations over the original
+    context, those divided by `factor` below `beta_slow`, a linear ramp
+    between."""
+    def dim_of(rotations: float) -> float:
+        return (head_dim * np.log(original / (rotations * 2 * np.pi))
+                / (2 * np.log(theta)))
+    low = max(int(np.floor(dim_of(beta_fast))), 0)
+    high = min(int(np.ceil(dim_of(beta_slow))), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    extra = rope_frequencies(head_dim, theta)
+    return (extra / factor * ramp + extra * (1 - ramp)).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * float(np.log(factor)) + 1.0 if factor > 1 else 1.0
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs: Optional[np.ndarray] = None) -> jax.Array:
+    """Rotary embedding. x: (..., seq, heads, head_dim); positions: (..., seq).
+    `freqs` (head_dim / 2,) replaces the plain frequencies of `theta`."""
     dtype = x.dtype
     head_dim = x.shape[-1]
-    freqs = jnp.asarray(rope_frequencies(head_dim, theta))
+    freqs = jnp.asarray(rope_frequencies(head_dim, theta) if freqs is None
+                        else freqs)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., seq, hd/2)
     cos = jnp.cos(angles)[..., :, None, :]  # broadcast over heads
     sin = jnp.sin(angles)[..., :, None, :]

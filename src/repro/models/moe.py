@@ -1,13 +1,26 @@
-"""Mixture-of-Experts: top-k router with capacity-bounded index dispatch.
+"""Mixture-of-Experts at one chip's share of an expert-parallel layer.
 
-TPU-native adaptation: instead of GShard's dense one-hot dispatch einsum (O(T·E·C)
-memory) we build (E, C) token-index tables with scatter, gather tokens into an
-(E, C, D) buffer (sharded expert-parallel over the ``model`` axis), run the expert
-matmuls as one batched einsum on the MXU, and combine with a weighted gather.
-Tokens over capacity are dropped (GShard semantics, capacity_factor default 1.25).
+The router scores every expert and picks `top_k` a token. The layer holds
+the experts `[expert_offset, expert_offset + held_experts)` and computes
+their part of the result for the tokens routed to them: the (token, choice)
+rows are sorted by held expert, and each expert's SwiGLU runs as one
+grouped matmul over its own rows (`jax.lax.ragged_dot`). So no
+token is dropped however skewed the routing, and the work follows the rows
+routed here, not a worst-case pad. What experts held on other chips would
+add is left out: on one chip the layer runs without its exchange. An uncut
+layer holds every expert (padded to `padded_experts`, so that EP-16 divides
+the stack; no token is routed to a pad).
 
-Padded experts (e.g. Qwen2-MoE's 60 -> 64 for EP-16) get -inf router logits and
-receive only padding slots.
+On a mesh the layer runs in a `shard_map`. Each shard of the batch sorts
+and multiplies its own rows, so no row crosses the data axis (a global
+sort there cost about 29 s a step of collectives at qwen3-moe train_4k
+scale in the production dry run). Each shard of the `model` axis holds
+its slice of the experts and computes their part, as one chip of an
+expert-parallel layer does, and the parts are summed over `model`.
+
+Shared experts run on every token, with Qwen2-MoE's sigmoid gate or none
+(DeepSeek). The balance loss is DeepSeek's sequence-wise one (`seq_aux`) or
+the Switch Transformer's.
 """
 from __future__ import annotations
 
@@ -16,26 +29,21 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
-from repro.parallel.constraints import BATCH, constrain
-
-
-def moe_capacity(num_tokens: int, num_experts: int, top_k: int,
-                 capacity_factor: float) -> int:
-    cap = int(np.ceil(top_k * num_tokens * capacity_factor / num_experts))
-    return max(8, ((cap + 7) // 8) * 8)  # pad to 8 for TPU lane alignment
+from repro.parallel.constraints import BATCH, constrain, expert_mesh
 
 
 def moe_init(key, cfg, dtype) -> Dict:
-    e = cfg.padded_experts
+    e, n = cfg.num_experts, cfg.held_experts
     d, f = cfg.d_model, cfg.moe_d_ff
     ks = jax.random.split(key, 6)
     sc, so = 1.0 / np.sqrt(d), 1.0 / np.sqrt(f)
     p = {
         "router": (jax.random.normal(ks[0], (d, e)) * sc).astype(jnp.float32),
-        "w_gate": (jax.random.normal(ks[1], (e, d, f)) * sc).astype(dtype),
-        "w_up": (jax.random.normal(ks[2], (e, d, f)) * sc).astype(dtype),
-        "w_down": (jax.random.normal(ks[3], (e, f, d)) * so).astype(dtype),
+        "w_gate": (jax.random.normal(ks[1], (n, d, f)) * sc).astype(dtype),
+        "w_up": (jax.random.normal(ks[2], (n, d, f)) * sc).astype(dtype),
+        "w_down": (jax.random.normal(ks[3], (n, f, d)) * so).astype(dtype),
     }
     if cfg.num_shared_experts:
         fs = cfg.shared_expert_d_ff
@@ -45,101 +53,103 @@ def moe_init(key, cfg, dtype) -> Dict:
             "w_down": (jax.random.normal(
                 jax.random.fold_in(ks[5], 1), (fs, d)) / np.sqrt(fs)).astype(dtype),
         }
-        p["shared_gate"] = jnp.zeros((d,), jnp.float32)
+        if cfg.shared_expert_gate:
+            p["shared_gate"] = jnp.zeros((d,), jnp.float32)
     return p
 
 
-def moe_groups(num_tokens: int) -> int:
-    """Dispatch groups (GShard-style). Groups map onto the data axis so the
-    position sort/scatter/gather stay SHARD-LOCAL — a global argsort over the
-    data axis cost ~29 s/step of collectives at qwen3-moe train_4k scale."""
-    for g in (16, 8, 4, 2):
-        if num_tokens % g == 0 and num_tokens // g >= 8:
-            return g
-    return 1
+def route(p: Dict, cfg, x: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x: (B, S, D) -> the chosen experts' weights and ids, each (B, S, k),
+    and the balance loss (before `aux_loss_alpha`). The router runs in
+    float32 over every expert."""
+    e, k = cfg.num_experts, cfg.top_k
+    logits = jnp.matmul(x.astype(jnp.float32), p["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)                     # (B, S, E)
+    top_w, top_e = jax.lax.top_k(probs, k)
+    if cfg.norm_topk_prob:
+        top_w = top_w / jnp.maximum(jnp.sum(top_w, -1, keepdims=True), 1e-9)
+    if cfg.seq_aux:
+        # each sequence's selections of e over the S * k / E an even split
+        # gives, times its mean probability of e (DeepSeek-V2)
+        s = x.shape[1]
+        ce = jnp.sum(jax.nn.one_hot(top_e, e, dtype=jnp.float32), axis=(1, 2))
+        ce = ce / (s * k / e)
+        aux = jnp.mean(jnp.sum(ce * jnp.mean(probs, axis=1), axis=-1))
+    else:
+        # Switch Transformer: top-1 share of tokens times mean probability
+        hard = jax.nn.one_hot(jnp.argmax(probs, -1), e, dtype=jnp.float32)
+        aux = e * jnp.sum(
+            jnp.mean(hard, axis=(0, 1)) * jnp.mean(probs, axis=(0, 1)))
+    return top_w, top_e, aux
+
+
+def _held_part(cfg, x, top_w, top_e, w_gate, w_up, w_down, offset):
+    """One batch shard's tokens x: (T, D) with their choices (T, k) -> what
+    the experts `[offset, offset + n)` of the stacks (n, ...) add to each
+    token: (T, D) float32."""
+    t, d = x.shape
+    k, n = cfg.top_k, w_gate.shape[0]
+    with jax.named_scope("moe.route"):
+        local = top_e.reshape(t * k) - offset
+        held = (local >= 0) & (local < n)
+        # rows of held experts first, grouped by expert; the rest after
+        group = jnp.where(held, local, n)
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=n + 1)[:n].astype(jnp.int32)
+        row_tok, row_held = order // k, held[order]
+
+    with jax.named_scope("moe.experts"):
+        # rows past the held groups are zeroed going in, and their
+        # cotangent going back, whatever the grouped matmul leaves there
+        xs = jnp.where(row_held[:, None], x[row_tok], 0)
+        g = jax.lax.ragged_dot(xs, w_gate, sizes)
+        u = jax.lax.ragged_dot(xs, w_up, sizes)
+        y = jax.lax.ragged_dot(jax.nn.silu(g) * u, w_down, sizes)
+        y = jnp.where(row_held[:, None], y, 0)
+        back = jnp.zeros((t * k,), jnp.int32).at[order].set(
+            jnp.arange(t * k, dtype=jnp.int32), unique_indices=True)
+        y = y.at[back].get(unique_indices=True).reshape(t, k, d)
+        w = jnp.where(held.reshape(t, k), top_w.reshape(t, k), 0.0)
+        return jnp.sum(y.astype(jnp.float32) * w[..., None], axis=1)
 
 
 def moe_apply(p: Dict, cfg, x: jax.Array):
-    """x: (B, S, D) -> (out: (B, S, D), aux_loss: scalar).
-
-    Grouped capacity dispatch: tokens are split into G groups aligned with
-    the data axis; routing positions, the (G, E, C) index table, and the
-    combine-gather are all group-local. Only the expert einsum crosses the
-    mesh (token <-> expert all-to-all, EP over "model").
-    """
+    """x: (B, S, D) -> (out: (B, S, D), aux_loss: scalar)."""
     b, s, d = x.shape
-    t = b * s
-    e = cfg.padded_experts
-    k = cfg.top_k
-    grp = moe_groups(t)
-    tg = t // grp
-    cap = moe_capacity(tg, e, k, cfg.capacity_factor)
-    xf = constrain(x.reshape(grp, tg, d), BATCH, None, None)
+    x = constrain(x, BATCH, None, None)
+    with jax.named_scope("moe.route"):
+        top_w, top_e, aux = route(p, cfg, x)
+    experts = (p["w_gate"], p["w_up"], p["w_down"])
+    mesh, axes, ep = expert_mesh(b, cfg.held_experts)
 
-    # --- routing (fp32) ---
-    logits = xf.astype(jnp.float32) @ p["router"]  # (G, Tg, E)
-    if e != cfg.num_experts:  # mask padded experts
-        pad_mask = jnp.arange(e) >= cfg.num_experts
-        logits = jnp.where(pad_mask[None, None, :], -1e30, logits)
-    gate_probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_e = jax.lax.top_k(gate_probs, k)  # (G, Tg, k)
-    top_w = top_w / jnp.maximum(jnp.sum(top_w, -1, keepdims=True), 1e-9)
+    def part(x, top_w, top_e, *experts):
+        offset = cfg.expert_offset
+        if ep:
+            offset = offset + jax.lax.axis_index(ep) * experts[0].shape[0]
+        out = _held_part(cfg, x.reshape(-1, d), top_w, top_e, *experts,
+                         offset)
+        return jax.lax.psum(out, ep) if ep else out
 
-    # Switch-style load-balance aux (reuses this router pass)
-    hard = jnp.argmax(gate_probs, -1).reshape(-1)
-    frac = jnp.zeros((e,), jnp.float32).at[hard].add(1.0) / t
-    aux = cfg.num_experts * jnp.sum(
-        frac * jnp.mean(gate_probs.reshape(t, e), axis=0))
+    if mesh is None:
+        out = part(x, top_w, top_e, *experts)
+    else:
+        rows = P(axes or None)
+        out = jax.shard_map(
+            lambda *a: part(*a)[None], mesh=mesh,
+            in_specs=(rows,) * 3 + (P(ep),) * 3, out_specs=rows,
+            axis_names=set(axes) | ({ep} if ep else set()), check_vma=False,
+        )(x, top_w, top_e, *experts).reshape(b * s, d)
 
-    # --- group-local capacity positions via stable sort ---
-    flat_e = top_e.reshape(grp, tg * k)
-    order = jnp.argsort(flat_e, axis=1, stable=True)
-    sorted_e = jnp.take_along_axis(flat_e, order, axis=1)
-    first = jax.vmap(lambda se: jnp.searchsorted(se, jnp.arange(e)))(sorted_e)
-    pos_sorted = jnp.arange(tg * k)[None, :] - \
-        jnp.take_along_axis(first, sorted_e, axis=1)
-    pos = jnp.zeros((grp, tg * k), jnp.int32).at[
-        jnp.arange(grp)[:, None], order].set(pos_sorted.astype(jnp.int32))
-    valid = pos < cap
-
-    # --- dispatch: (G, E, C) token-index table, then gather ---
-    tok_ids = jnp.repeat(jnp.arange(tg), k)[None, :]              # (1, Tg*k)
-    safe_pos = jnp.where(valid, pos, cap)
-    table = jnp.full((grp, e, cap + 1), tg, jnp.int32)            # tg = "none"
-    gidx = jnp.broadcast_to(jnp.arange(grp)[:, None], flat_e.shape)
-    table = table.at[gidx, flat_e, safe_pos].set(
-        jnp.where(valid, jnp.broadcast_to(tok_ids, flat_e.shape), tg))
-    table = constrain(table[:, :, :cap], BATCH, None, None)       # (G, E, C)
-    xpad = jnp.concatenate([xf, jnp.zeros((grp, 1, d), xf.dtype)], axis=1)
-    dispatched = jnp.take_along_axis(
-        xpad[:, :, None, :], table[..., None], axis=1)            # (G, E, C, D)
-    # NOTE perf: constraining this buffer 2D (groups x experts) makes XLA's
-    # gather partitioning replicate operands (measured 30.9 s -> 271 s
-    # collective — refuted hypothesis, EXPERIMENTS.md §Perf). Group-sharded
-    # only; the true all-to-all dispatch needs an explicit shard_map
-    # (future work, blocked on the Shardy partitioner).
-    dispatched = constrain(dispatched, BATCH, None, None, None)
-
-    # --- expert compute (EP over "model"; groups gathered per expert) ---
-    g_ = jnp.einsum("gecd,edf->gecf", dispatched, p["w_gate"])
-    u = jnp.einsum("gecd,edf->gecf", dispatched, p["w_up"])
-    y = jnp.einsum("gecf,efd->gecd", jax.nn.silu(g_) * u, p["w_down"])
-    y = constrain(y, BATCH, None, None, None)
-
-    # --- combine: group-local weighted gather back to tokens ---
-    flat_pos = jnp.minimum(pos, cap - 1).reshape(grp, tg, k)
-    gathered = y[jnp.arange(grp)[:, None, None], top_e, flat_pos]
-    gathered = constrain(gathered, BATCH, None, None, None)       # (G,Tg,k,D)
-    w = (top_w * valid.reshape(grp, tg, k)).astype(jnp.float32)
-    out = jnp.sum(gathered.astype(jnp.float32) * w[..., None], axis=2)
-
-    # --- shared experts (Qwen2-MoE): dense MLP + sigmoid gate ---
     if "shared" in p:
-        sp = p["shared"]
-        sg = jax.nn.silu(xf @ sp["w_gate"]) * (xf @ sp["w_up"])
-        shared_out = sg @ sp["w_down"]
-        gate = jax.nn.sigmoid(
-            xf.astype(jnp.float32) @ p["shared_gate"][:, None])
-        out = out + shared_out.astype(jnp.float32) * gate
+        with jax.named_scope("moe.shared"):
+            xf = x.reshape(b * s, d)
+            sp = p["shared"]
+            sg = jax.nn.silu(xf @ sp["w_gate"]) * (xf @ sp["w_up"])
+            shared_out = (sg @ sp["w_down"]).astype(jnp.float32)
+            if "shared_gate" in p:
+                shared_out = shared_out * jax.nn.sigmoid(
+                    xf.astype(jnp.float32) @ p["shared_gate"][:, None])
+            out = out + shared_out
 
     return out.reshape(b, s, d).astype(x.dtype), aux

@@ -185,7 +185,8 @@ def _xent(logits: jax.Array, labels: jax.Array) -> jax.Array:
 # --------------------------------------------------------------------------- #
 def _build_decoder_lm(cfg: ArchConfig) -> Model:
     dtype = _dtype(cfg)
-    n_layers = cfg.num_layers
+    n_dense = cfg.first_k_dense
+    n_layers = cfg.num_layers - n_dense      # the scanned stack
     v = cfg.padded_vocab
 
     def init(key):
@@ -196,6 +197,10 @@ def _build_decoder_lm(cfg: ArchConfig) -> Model:
                 lambda k: _attn_block_init(k, cfg, dtype), k_blocks, n_layers),
             "final_norm": jnp.zeros((cfg.d_model,), dtype),
         }
+        if n_dense:   # leading dense blocks, each its own (unstacked) tree
+            params["dense"] = [
+                _attn_block_init(k, cfg, dtype, d_ff=cfg.d_ff) for k in
+                jax.random.split(jax.random.fold_in(k_blocks, 1), n_dense)]
         if not cfg.tie_embeddings:
             params["lm_head"] = embed_init(k_head, v, cfg.d_model, dtype)
         return params
@@ -212,8 +217,11 @@ def _build_decoder_lm(cfg: ArchConfig) -> Model:
 
     def _backbone(params, x):
         body = _remat(lambda carry, p: _attn_block(p, cfg, *carry), cfg)
+        carry = (x, jnp.zeros((), jnp.float32))
+        for p in params.get("dense", ()):
+            carry = body(carry, p)
         (x, aux), _ = scan_layers(
-            lambda c, p: (body(c, p), None), (x, jnp.zeros((), jnp.float32)),
+            lambda c, p: (body(c, p), None), carry,
             params["blocks"], length=n_layers)
         return rms_norm(x, params["final_norm"]), aux
 
@@ -235,15 +243,23 @@ def _build_decoder_lm(cfg: ArchConfig) -> Model:
         npatch = cfg.num_patch_tokens
         head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
         l = chunked_xent(head, x[:, npatch:] if npatch else x, labels)
-        total = l + 0.01 * aux
+        total = l + cfg.aux_loss_alpha * aux
         return total, {"xent": l, "aux": aux}
 
+    def _no_cache():
+        if cfg.is_mla or n_dense:
+            raise NotImplementedError(
+                f"{cfg.name}: serving needs a latent KV cache and leading "
+                "dense blocks in prefill and decode, which are not built")
+
     def cache_specs(batch: int, max_len: int):
+        _no_cache()
         kh, hd = cfg.num_kv_heads, cfg.resolved_head_dim
         kv = jax.ShapeDtypeStruct((n_layers, batch, max_len, kh, hd), dtype)
         return {"k": kv, "v": kv, "index": jax.ShapeDtypeStruct((), jnp.int32)}
 
     def prefill(params, batch):
+        _no_cache()
         tokens = batch["tokens"]
         max_len = batch.get("max_len", tokens.shape[1] + (cfg.num_patch_tokens or 0))
         x = _embed_inputs(params, batch, tokens)
@@ -272,6 +288,7 @@ def _build_decoder_lm(cfg: ArchConfig) -> Model:
         return logits, cache
 
     def decode_step(params, cache, token):
+        _no_cache()
         x = embed_lookup(params["embed"], token[:, None])  # (B,1,D)
         index = cache["index"]
 
@@ -706,12 +723,14 @@ def param_count(cfg: ArchConfig) -> int:
 
 
 def active_param_count(cfg: ArchConfig) -> int:
-    """Params touched per token (MoE: top_k of routed experts + shared)."""
+    """Params touched per token (MoE: top_k of routed experts + shared; of
+    a share of the experts, top_k at the share's expected rate)."""
     total = param_count(cfg)
     if not cfg.is_moe:
         return total
-    e = cfg.padded_experts
+    n_moe = cfg.num_layers - cfg.first_k_dense
     per_expert = 3 * cfg.d_model * cfg.moe_d_ff
-    routed_all = cfg.num_layers * e * per_expert
-    routed_active = cfg.num_layers * cfg.top_k * per_expert
-    return total - routed_all + routed_active
+    share = cfg.experts_held / cfg.num_experts if cfg.experts_held else 1.0
+    routed_all = n_moe * cfg.held_experts * per_expert
+    routed_active = n_moe * cfg.top_k * share * per_expert
+    return int(total - routed_all + routed_active)

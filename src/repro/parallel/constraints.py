@@ -33,6 +33,33 @@ def _mesh_shape():
         return {}
 
 
+def _fit(mesh, dim: int, names):
+    """The axes of `names` that cut `dim` evenly, in order."""
+    kept, div = [], 1
+    for a in names:
+        sz = mesh.get(a)
+        if sz and dim % (div * sz) == 0:
+            kept.append(a)
+            div *= sz
+    return kept
+
+
+def expert_mesh(batch: int, experts: int):
+    """For a `shard_map` of an expert layer: the ambient mesh, the batch
+    axes that cut a leading dim of `batch`, and "model" if it cuts the
+    `experts` held (else None); (None, (), None) when neither cuts."""
+    mesh = {a: n for a, n in _mesh_shape().items() if n > 1}
+    kept = _fit(mesh, batch, BATCH)
+    ep = "model" if _fit(mesh, experts, ("model",)) else None
+    if not kept and ep is None:
+        return None, (), None
+    am = jax.sharding.get_abstract_mesh()
+    if am is None or am.empty:
+        from jax._src import mesh as mesh_lib
+        am = mesh_lib.thread_resources.env.physical_mesh
+    return am, tuple(kept), ep
+
+
 def constrain(x, *parts):
     """constrain(x, ("pod","data"), "model", None) — axes missing from the
     ambient mesh are dropped; axes that don't divide the dim are dropped;
@@ -46,13 +73,7 @@ def constrain(x, *parts):
             if p is None:
                 fixed.append(None)
                 continue
-            names = p if isinstance(p, (tuple, list)) else (p,)
-            kept, div = [], 1
-            for a in names:
-                sz = mesh.get(a)
-                if sz and dim % (div * sz) == 0:
-                    kept.append(a)
-                    div *= sz
+            kept = _fit(mesh, dim, p if isinstance(p, (tuple, list)) else (p,))
             fixed.append(tuple(kept) if len(kept) > 1
                          else (kept[0] if kept else None))
         return jax.lax.with_sharding_constraint(x, P(*fixed))

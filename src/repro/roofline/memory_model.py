@@ -75,10 +75,9 @@ def _activation_traffic(cfg: ArchConfig, shape: ShapeConfig, mesh: Mesh,
             + 2 * shard(cfg.num_heads, tp) * hd
         if cfg.is_moe:
             fe = cfg.moe_d_ff
-            e_loc = shard(cfg.padded_experts, tp)
-            # dispatch buffer (E,C,D) write+read + expert h (E,C,Fe) w+r + out
-            cap_ratio = cfg.top_k * cfg.capacity_factor
-            per_layer += cap_ratio * (4 * d + 4 * fe)
+            # dropless bound: each token's top_k rows of the sorted buffer
+            # (rows, D) write+read + expert h (rows, Fe) w+r + out
+            per_layer += cfg.top_k * (4 * d + 4 * fe)
             if cfg.num_shared_experts:
                 per_layer += 4 * shard(cfg.shared_expert_d_ff, tp) + 2 * d
         else:

@@ -121,6 +121,58 @@ def test_loop_step_fits_one_chip(one_chip):
     assert _peak(c) < V5E_HBM
 
 
+def test_expert_share_step_fits_one_chip(one_chip):
+    """The loop's step of the benchmark's `deepseek-v2-lite-e8` (the dense
+    layer and 5 MoE layers at published widths, 8 of 64 experts held, 2 x
+    4,096 tokens, full remat) fits the chip's HBM beside its donated state,
+    and the held experts' matmuls are the chip's own ragged dots."""
+    import json
+    from pathlib import Path
+    from repro.configs import ArchConfig
+    conf = json.loads((Path(__file__).resolve().parents[1] / "bench"
+                       / "configs" / "deepseek-v2-lite-e8.json").read_text())
+    model = build_model(ArchConfig(**conf["arch"]))
+    state = jax.tree.map(lambda s: _on(one_chip, s.shape, s.dtype),
+                         make_state_specs(model))
+    tr = conf["train"]
+    batch = {"tokens": _on(one_chip, (tr["global_batch"], tr["seq_len"] + 1),
+                           jnp.int32)}
+    c = loop_step(model, HP).lower(state, batch).compile()
+    m = c.memory_analysis()
+    assert m.alias_size_in_bytes >= 0.99 * m.argument_size_in_bytes
+    assert _peak(c) < V5E_HBM
+    assert "ragged-dot" in c.as_text()
+
+
+@pytest.mark.parametrize("mesh_shape", [(4, 1), (2, 2)])
+def test_expert_layer_keeps_rows_on_their_data_shard(topo, mesh_shape):
+    """`build_train_step` of a small MoE model on a data x model mesh of
+    described chips: each shard's grouped matmul is the chip's ragged dot
+    over its own rows with the experts it holds, and the expert layer
+    gathers nothing across chips: its collectives are sums (the balance
+    loss's over the batch, the model shards' parts and their gradients)."""
+    import re
+    from repro.configs import reduce_for_smoke
+    model = build_model(reduce_for_smoke(get_arch("qwen3-moe-30b-a3b")))
+    mesh = make_mesh(mesh_shape, ("data", "model"), devices=topo.devices[:4])
+    shape = ShapeConfig("t", 128, 8, "train")
+    art = build_train_step(model, mesh, HP, shape=shape)
+    on = lambda specs, pspecs: jax.tree.map(
+        lambda s, sh: _on(sh, s.shape, s.dtype), specs,
+        to_named(pspecs, mesh))
+    with mesh:
+        c = art.step_fn.lower(
+            on(make_state_specs(model), art.plan.state_pspecs),
+            on(model.input_specs(shape), art.input_pspecs)).compile()
+    text = c.as_text()
+    assert "ragged-dot" in text
+    layer = [line for line in text.splitlines()
+             if re.search(r"(all-gather|all-to-all|all-reduce)(-start)?\(",
+                          line)
+             and re.search(r"closed_call/(moe\.|shard_map/)", line)]
+    assert layer and all(" all-reduce" in line for line in layer), layer
+
+
 def test_sharded_step_permutes_backup_across_chips(topo):
     """`build_train_step` on a (4, 1) data mesh of described chips: the
     instant checkpoint is a collective-permute in the compiled program."""
