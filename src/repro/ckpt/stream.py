@@ -42,7 +42,9 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import os
 import zlib
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -55,6 +57,17 @@ from repro.launch.spans import count, span
 PyTree = Any
 DEFAULT_QUANTUM = 1 << 20          # 1 MiB — the paper's chunk granularity
 _SEP = "|"
+
+# A large artifact's chunk CRCs are taken on a pool of host threads, one
+# contiguous run of chunks a thread: zlib.crc32 releases the GIL on buffers
+# over 5 KiB. Measured on an 8-CPU x86 host: 1 GiB in 1 MiB chunks at 6.8
+# GB/s on one thread and 38 GB/s on 8; 4 MiB in 1 MiB chunks 0.29 ms pooled
+# against 0.51 ms serial, the ~0.1 ms dispatch a tenth of the serial time
+# from 8 MiB; 64 MiB in 6 and 16 KiB chunks slower pooled (the threads queue
+# for the GIL between calls), in 64 KiB chunks 1.1x and 1 MiB 1.5x faster.
+_POOL_MIN_BYTES = 8 << 20
+_POOL_MIN_QUANTUM = 64 << 10
+_crc_pool: Tuple[int, int, Optional[ThreadPoolExecutor]] = (-1, 0, None)
 
 
 # --------------------------------------------------------------------------- #
@@ -93,6 +106,44 @@ def _leaf_records(tree: PyTree) -> List[Tuple[str, np.ndarray]]:
     return out
 
 
+def _crc_threads() -> Tuple[int, ThreadPoolExecutor]:
+    """(threads, pool): this process's CRC pool, a thread for each CPU the
+    process may run on. Made on first use in each process, so a forked
+    child never waits on threads that stayed with its parent. Two threads
+    that race here each make a pool and one is kept; the other finishes
+    its runs and is collected."""
+    global _crc_pool
+    pid = os.getpid()
+    if _crc_pool[0] != pid:
+        width = len(os.sched_getaffinity(0))
+        _crc_pool = (pid, width, ThreadPoolExecutor(
+            width, thread_name_prefix="stream-crc"))
+    return _crc_pool[1], _crc_pool[2]
+
+
+def _crc_run(view: memoryview, quantum: int, lo: int, hi: int) -> List[int]:
+    return [zlib.crc32(view[i * quantum:(i + 1) * quantum])
+            for i in range(lo, hi)]
+
+
+def _pooled_crcs(data: Union[bytes, memoryview], quantum: int, n: int
+                 ) -> Optional[List[int]]:
+    """The CRC32s of `data`'s `n` chunks, taken on the CRC pool with at
+    least two chunks a thread; None where the artifact is too small or its
+    chunks too short for the pool to pay."""
+    if len(data) < _POOL_MIN_BYTES or quantum < _POOL_MIN_QUANTUM:
+        return None
+    width, pool = _crc_threads()
+    k = min(width, n // 2)
+    if k < 2:
+        return None
+    view = memoryview(data)
+    bounds = [n * j // k for j in range(k + 1)]
+    runs = [pool.submit(_crc_run, view, quantum, lo, hi)
+            for lo, hi in zip(bounds, bounds[1:])]
+    return [crc for run in runs for crc in run.result()]
+
+
 class ChunkedStream:
     """A checkpoint artifact cut into CRC'd fixed-size quanta.
 
@@ -113,13 +164,16 @@ class ChunkedStream:
         n = max(1, math.ceil(len(data) / quantum))
         self.chunks: List[StreamChunk] = []
         with span("stream.chunk"):
+            crcs = _pooled_crcs(data, quantum, n)
             for i in range(n):
                 payload = data[i * quantum:(i + 1) * quantum]
                 self.chunks.append(StreamChunk(
                     stream_id, i, n, i * quantum, payload,
-                    zlib.crc32(payload), self.total_bytes))
+                    zlib.crc32(payload) if crcs is None else crcs[i],
+                    self.total_bytes))
             count("bytes", self.total_bytes)
             count("chunks", n)
+            count("parallel_bytes", 0 if crcs is None else self.total_bytes)
 
     @property
     def n_chunks(self) -> int:

@@ -3,15 +3,21 @@ CkptEngine paths through the shared transport, scheduler-derived failover
 timelines (preemption delays recovery), multi-failure resume-from-partial-
 chunks on the cluster, and the emergent FCR hiding condition."""
 import dataclasses
+import multiprocessing
+import os
+import time
+import zlib
 from pathlib import Path
 
 import numpy as np
 import pytest
 
+from repro.ckpt import stream as stream_mod
 from repro.ckpt.engine import CkptEngine, CkptEngineConfig
 from repro.ckpt.stream import (ChunkedStream, StreamAssembler, StreamChunk,
                                StreamTransport, stream_pytree)
 from repro.core.lccl import LinkScheduler
+from repro.launch import spans
 from repro.runtime.recovery import FaultScript
 
 
@@ -64,6 +70,78 @@ def test_assembler_resumes_from_partial():
     for seq in asm.missing():
         asm.offer(cs.chunks[seq])
     assert asm.complete
+
+
+# --------------------------------------------------------------------------- #
+# CRCs on the pool of host threads
+# --------------------------------------------------------------------------- #
+_FANOUT = stream_mod._POOL_MIN_BYTES
+_POOLED = len(os.sched_getaffinity(0)) >= 2
+# both sides of the fan-out size, and lengths 1 MiB chunks do not divide
+_SIZES = [_FANOUT // 2 + 7, _FANOUT - 1, _FANOUT, 2 * _FANOUT + 12345]
+
+
+def _cut(nbytes):
+    """`nbytes` random bytes, their stream in 1 MiB chunks, the span that
+    cut it, and the chunks' CRCs taken one after another on this thread."""
+    raw = np.random.default_rng(nbytes).integers(0, 256, nbytes, np.uint8)
+    t0 = time.perf_counter()
+    cs = ChunkedStream.from_pytree("big", {"x": raw})
+    cut = [s for s in spans.spans(t0) if s.name == "stream.chunk"]
+    q = cs.quantum
+    serial = [(i, o, len(raw[o:o + q]), zlib.crc32(raw[o:o + q].tobytes()))
+              for i, o in enumerate(range(0, nbytes, q))]
+    return raw, cs, cut, serial
+
+
+def _cut_in_child(nbytes, conn):
+    _, cs, cut, serial = _cut(nbytes)
+    conn.send(([tuple(c.manifest_entry().values()) for c in cs.chunks],
+               serial, cut[0].counts["parallel_bytes"]))
+    conn.close()
+
+
+@pytest.mark.parametrize("nbytes", _SIZES)
+@pytest.mark.parametrize("check", ["crcs", "counter", "nack", "fork"])
+def test_pooled_crcs_equal_one_thread(check, nbytes):
+    pooled = _POOLED and nbytes >= _FANOUT
+    if check == "fork":
+        _cut(_FANOUT)                      # the parent's pool exists first
+        ctx = multiprocessing.get_context("fork")
+        recv, send = ctx.Pipe(duplex=False)
+        child = ctx.Process(target=_cut_in_child, args=(nbytes, send))
+        child.start()
+        answered = recv.poll(60)
+        if not answered:
+            child.kill()
+        child.join(60)
+        assert answered, "the forked child cut no stream"
+        got, serial, parallel = recv.recv()
+        assert child.exitcode == 0
+        assert got == serial
+        assert parallel == (nbytes if pooled else 0)
+        return
+    raw, cs, cut, serial = _cut(nbytes)
+    if check == "crcs":
+        assert [(c.seq, c.offset, c.nbytes, c.crc) for c in cs.chunks] \
+            == serial
+        assert all(c.payload.readonly for c in cs.chunks)
+    elif check == "counter":
+        assert len(cut) == 1
+        assert cut[0].counts == {"bytes": nbytes, "chunks": cs.n_chunks,
+                                 "parallel_bytes": nbytes if pooled else 0}
+    else:
+        good = cs.chunks[1]
+        flipped = bytes([good.payload[0] ^ 0xFF]) + good.payload[1:]
+        assert good.verify()
+        assert not dataclasses.replace(good, payload=flipped).verify()
+        tp = StreamTransport(LinkScheduler(1e9, quantum=1 << 20))
+        asm = StreamAssembler.for_stream(cs)
+        tp.corrupt_once("big", 1)
+        ticket = tp.send(cs, 0.0, assembler=asm)
+        tp.drain()
+        assert ticket.complete and asm.rejected == 1 and tp.nacks_sent == 1
+        assert asm.data() == raw.tobytes()
 
 
 # --------------------------------------------------------------------------- #
