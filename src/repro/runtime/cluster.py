@@ -22,7 +22,7 @@ import dataclasses
 import warnings
 from dataclasses import dataclass, field
 from pathlib import Path
-from typing import Any, Callable, Dict, List, Optional, Tuple, Union
+from typing import Any, Dict, List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -41,7 +41,7 @@ from repro.data.indexer import TidIndexer
 from repro.data.loader import PrefetchingLoader, SyntheticTokens
 from repro.launch.spans import span
 from repro.models import build_model
-from repro.optim import AdamWConfig, adamw_update, cast_params, cosine_schedule
+from repro.optim import AdamWConfig
 # recovery machinery lives in runtime/recovery.py; the vector/shard helpers
 # and RecoveryReport are re-exported here for back-compat imports
 from repro.runtime.recovery import (FaultScript, HostVectorPool,
@@ -54,7 +54,7 @@ from repro.runtime.reliability import (ReliabilityConfig,
                                        ReliabilityController,
                                        ReliabilityEvent)
 from repro.train.state import init_state
-from repro.train.step import step_traffic, submit_step_traffic
+from repro.train.step import loop_step, step_traffic, submit_step_traffic
 
 PyTree = Any
 
@@ -132,32 +132,6 @@ def _split_legacy_kwargs(kw: Dict[str, Any],
     cc = dataclasses.replace(cluster or ClusterConfig(), **c_over)
     fc = dataclasses.replace(fabric or FabricConfig(), **f_over)
     return cc, fc
-
-
-def loop_step(model, hp: AdamWConfig) -> Callable:
-    """The cluster's jitted train step, ``(state, batch) -> (state, loss)``,
-    on one device. The state is donated: the step updates it in place, so
-    at published width one copy of the state (not two) is resident."""
-
-    def forward(p, batch):
-        with jax.named_scope("forward"):
-            return model.loss(p, batch)
-
-    def step(state, batch):
-        # the backward's ops carry the forward's scope under `transpose`
-        (loss, aux), grads = jax.value_and_grad(
-            forward, has_aux=True)(state["params"], batch)
-        with jax.named_scope("optimizer"):
-            lr = cosine_schedule(state["step"], lr=hp.lr,
-                                 warmup_steps=hp.warmup_steps,
-                                 total_steps=hp.total_steps)
-            _, new_opt = adamw_update(grads, state["opt"], state["step"], hp,
-                                      lr)
-            new_params = cast_params(new_opt["master"], state["params"])
-        return ({"step": state["step"] + 1, "params": new_params,
-                 "opt": new_opt}, loss)
-
-    return jax.jit(step, donate_argnums=(0,))
 
 
 @dataclass

@@ -163,12 +163,12 @@ def compute_recovery_timeline(n_workers: int, state_bytes_per_worker: float,
     """Checkpoint-free recovery flow ("All is Not Lost", PAPERS.md): same
     orchestration legs as FFTrainer, but the state leg is a REPLAY leg —
     healthy neighbors rebuild the lost worker's state by redundant compute
-    at the modeled recompute rate (train/step.py `ReplayCostModel`). No
+    at the modeled recompute rate (runtime/recovery.py `ReplayCostModel`). No
     fabric bytes move, so the leg is independent of link bandwidth, TRAIN
     contention, and storm damage; the bill lands on `replay_compute`
     seconds instead (plus `compute_seconds_burned`, the total worker
     compute spent, reported out-of-timeline)."""
-    from repro.train.step import ReplayCostModel, replay_compute_cost
+    from repro.runtime.recovery import ReplayCostModel, replay_compute_cost
     detection = detection if detection is not None else DetectionTimeline()
     cost = replay_compute_cost(state_bytes_per_worker,
                                n_replayers=n_replayers,
@@ -203,7 +203,7 @@ def hybrid_recovery_timeline(n_workers: int, state_bytes_per_worker: float,
     phase takes whichever finishes first (both start once pods are up).
     The closed-form analogue of `HybridRecovery` in runtime/recovery.py —
     useful for the table5 what-if rows without building a cluster."""
-    from repro.train.step import ReplayCostModel, replay_compute_cost
+    from repro.runtime.recovery import ReplayCostModel, replay_compute_cost
     detection = detection if detection is not None else DetectionTimeline()
     t_net = costs.conn_base + costs.conn_per_worker * n_workers
     t_stream = costs.state_ramp_fft + schedule_state_phase(

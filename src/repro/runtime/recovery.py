@@ -16,7 +16,7 @@ Three policies ship:
     back to the periodic full checkpoint (with rollback) when the neighbor
     copy died too.
   * `ComputeRecovery` — checkpoint-free: healthy neighbors replay redundant
-    compute (train/step.py `ReplayCostModel`) to rebuild the lost shards.
+    compute (`ReplayCostModel` below) to rebuild the lost shards.
     Costs worker compute-seconds, submits NO STATE traffic, and therefore
     stays viable when `inject_storm` has darkened the cross-pod edges.
   * `HybridRecovery` — per-failed-worker choice by estimated completion
@@ -40,7 +40,6 @@ import numpy as np
 
 from repro.ckpt.stream import ChunkedStream, StreamAssembler
 from repro.launch.spans import count, span
-from repro.train.step import ReplayCost, ReplayCostModel, replay_compute_cost
 
 PyTree = Any
 
@@ -192,6 +191,56 @@ def orchestration_timeline(cluster, faults: FaultScript) -> Dict[str, float]:
         "pod_creation": 7.0 if faults.hardware else 0.5,
         "dependency_install": 0.0,
     }
+
+
+# --------------------------------------------------------------------------- #
+# Checkpoint-free replay-compute cost model ("All is Not Lost", PAPERS.md):
+# instead of streaming a lost worker's state over the fabric, its pipeline/DP
+# neighbors re-execute redundant compute to rebuild the shard from their own
+# replicas — recovery then costs worker compute-seconds instead of fabric
+# bytes, which is exactly the currency that stays cheap when a storm has
+# darkened the cross-pod links.
+# --------------------------------------------------------------------------- #
+@dataclass(frozen=True)
+class ReplayCostModel:
+    """Knobs for compute-based (checkpoint-free) recovery.
+
+    `recompute_rate` is how many bytes of lost optimizer/param state one
+    replaying worker can rebuild per second of redundant compute (forward
+    replay at the training step rate, amortized). `replay_overhead`
+    multiplies the state volume: redundant compute interleaves with the
+    replayer's own step, so rebuilding B bytes burns more than B worth of
+    step time. `setup_seconds` is the fixed cost of re-materializing
+    activations and swapping the replay schedule in."""
+    recompute_rate: float = 2e9        # bytes of state rebuilt / s / replayer
+    replay_overhead: float = 1.25      # redundant-compute amplification
+    setup_seconds: float = 0.5         # schedule swap + activation re-mat
+
+
+@dataclass(frozen=True)
+class ReplayCost:
+    """One failed worker's replay bill: `wall_seconds` is the elapsed time
+    with the replayers working in parallel; `compute_seconds` is the total
+    worker compute burned (the resource compute-based recovery spends
+    instead of fabric bytes)."""
+    wall_seconds: float
+    compute_seconds: float
+    bytes_rebuilt: float
+    n_replayers: int
+
+
+def replay_compute_cost(state_bytes: float, n_replayers: int = 2,
+                        model: ReplayCostModel = ReplayCostModel()
+                        ) -> ReplayCost:
+    """Cost of rebuilding `state_bytes` of a lost worker's state by replaying
+    redundant compute on `n_replayers` healthy neighbors. The replayers
+    split the replay evenly, so wall time divides by their count while the
+    total compute burned does not. Submits NO fabric traffic."""
+    n = max(int(n_replayers), 1)
+    burn = state_bytes * model.replay_overhead / model.recompute_rate
+    wall = model.setup_seconds + burn / n
+    return ReplayCost(wall_seconds=wall, compute_seconds=burn,
+                      bytes_rebuilt=float(state_bytes), n_replayers=n)
 
 
 # --------------------------------------------------------------------------- #

@@ -1,37 +1,85 @@
-"""Train/serve step builders.
+"""The train step: one update body and its two wrappers.
 
-``build_train_step`` returns a jit-compiled SPMD step:
+``update_body(model, hp)`` is the step's math, un-jitted: the loss and its
+gradient, the cosine schedule, the AdamW update and the cast back to the
+parameters' dtype. Two wrappers jit it:
 
-    new_state, metrics, backup = step(state, batch)
+  * ``loop_step`` on one device, the failover loop's step (runtime/cluster.py):
 
-with the paper's instant checkpoint fused in: ``backup`` is the ZeRO-unique
-optimizer shard permuted one hop along the DP ring (core/instant.py), an
-explicit collective-permute in the compiled HLO that XLA overlaps with
-compute. ``backup`` leaves are None when instant checkpointing is disabled or
-the leaf is razor-redundant.
+        new_state, loss = step(state, batch)
 
-Optional beyond-paper feature: int8 cross-pod gradient compression
-(parallel/compression.py) applied before the optimizer update.
+  * ``build_train_step`` on a mesh, an SPMD step with the paper's instant
+    checkpoint fused in:
+
+        new_state, metrics, backup = step(state, batch)
+
+    ``backup`` is the ZeRO-unique optimizer shard permuted one hop along the
+    DP ring (core/instant.py), an explicit collective-permute in the compiled
+    HLO that XLA overlaps with compute. ``backup`` leaves are None when
+    instant checkpointing is disabled or the leaf is razor-redundant.
+
+The module also holds, until it moves to the simulator, the wire accounting
+of one iteration (``TrafficProfile`` and the functions that build and submit
+it).
 """
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.instant import neighbor_backup
 from repro.core.razor import RazorPlan, razor_plan
 from repro.models import Model
+from repro.models.modes import fsdp_unshard
 from repro.optim import AdamWConfig, adamw_update, cast_params, cosine_schedule
 from repro.parallel import sharding as shd
 from repro.train.state import StatePlan, make_state_plan
 
 PyTree = Any
+
+# the mesh axis the ZeRO shards and the instant checkpoint's ring run along
+DATA_AXIS = "data"
+
+
+def update_body(model: Model, hp: AdamWConfig) -> Callable:
+    """The un-jitted ``(state, batch) -> (new_state, loss, aux, lr)``."""
+
+    def forward(p, batch):
+        with jax.named_scope("forward"):
+            return model.loss(p, batch)
+
+    def body(state, batch):
+        # the backward's ops carry the forward's scope under `transpose`
+        (loss, aux), grads = jax.value_and_grad(
+            forward, has_aux=True)(state["params"], batch)
+        with jax.named_scope("optimizer"):
+            lr = cosine_schedule(state["step"], lr=hp.lr,
+                                 warmup_steps=hp.warmup_steps,
+                                 total_steps=hp.total_steps)
+            _, new_opt = adamw_update(grads, state["opt"], state["step"], hp,
+                                      lr)
+            new_params = cast_params(new_opt["master"], state["params"])
+        return ({"step": state["step"] + 1, "params": new_params,
+                 "opt": new_opt}, loss, aux, lr)
+
+    return body
+
+
+def loop_step(model: Model, hp: AdamWConfig) -> Callable:
+    """The loop's jitted train step, ``(state, batch) -> (state, loss)``,
+    on one device. The state is donated: the step updates it in place, so
+    at published width one copy of the state (not two) is resident."""
+    body = update_body(model, hp)
+
+    def step(state, batch):
+        new_state, loss, _, _ = body(state, batch)
+        return new_state, loss
+
+    return jax.jit(step, donate_argnums=(0,))
 
 
 @dataclass(frozen=True)
@@ -49,20 +97,19 @@ def build_train_step(
     hp: AdamWConfig = AdamWConfig(),
     *,
     instant_ckpt: bool = True,
-    backup_axis: str = "data",
-    compress_pod_grads: bool = False,
-    fsdp_params: bool = True,
-    microbatches: int = 1,
     donate: bool = True,
     shape=None,
 ) -> StepArtifacts:
+    """The update body on `mesh`: FSDP-stored parameters, ZeRO-sharded
+    optimizer state and, with `instant_ckpt`, the razor-unique optimizer
+    shard sent one hop along the data axis as ``backup``."""
     cfg = model.cfg
-    plan = make_state_plan(model, mesh, fsdp_params=fsdp_params)
+    plan = make_state_plan(model, mesh, fsdp_params=True)
     razor = razor_plan(plan.state_specs["opt"], plan.opt_pspecs,
-                       plan.state_specs["params"], mesh, zero_axis=backup_axis)
+                       plan.state_specs["params"], mesh, zero_axis=DATA_AXIS)
 
     # backup = unique opt leaves only (razor) when instant ckpt is on
-    if instant_ckpt and mesh.shape.get(backup_axis, 1) > 1:
+    if instant_ckpt and mesh.shape.get(DATA_AXIS, 1) > 1:
         backup_pspecs = jax.tree.map(
             lambda ps, m: ps if m else None, plan.opt_pspecs, razor.unique_mask,
             is_leaf=lambda x: isinstance(x, P))
@@ -72,56 +119,15 @@ def build_train_step(
 
     input_pspecs = shd.input_pspecs(cfg, model.input_specs(shape), mesh) \
         if shape else None
-
-    use_compression = (compress_pod_grads and "pod" in mesh.axis_names
-                       and mesh.shape["pod"] > 1 and input_pspecs is not None)
+    body = update_body(model, hp)
 
     def train_step(state, batch):
-        if use_compression:
-            from repro.parallel.compression import \
-                pod_compressed_value_and_grad
-            vg = pod_compressed_value_and_grad(
-                lambda p, b: model.loss(p, b), mesh, plan.param_pspecs,
-                input_pspecs)
-            (loss, aux), grads = vg(state["params"], batch)
-        elif microbatches > 1:
-            # gradient accumulation: scan over microbatches — divides the live
-            # activation footprint by `microbatches` at the cost of
-            # re-gathering FSDP-sharded params once per microbatch
-            mb = jax.tree.map(
-                lambda x: x.reshape(microbatches, x.shape[0] // microbatches,
-                                    *x.shape[1:]), batch)
-            gzero = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), state["params"])
-
-            def mb_body(gsum, b):
-                (l, aux), g = jax.value_and_grad(
-                    lambda p: model.loss(p, b), has_aux=True)(state["params"])
-                gsum = jax.tree.map(
-                    lambda a, x: a + x.astype(jnp.float32), gsum, g)
-                return gsum, (l, aux)
-
-            gsum, (ls, auxs) = jax.lax.scan(mb_body, gzero, mb)
-            grads = jax.tree.map(lambda g: g / microbatches, gsum)
-            loss = jnp.mean(ls)
-            aux = jax.tree.map(jnp.mean, auxs)
-        else:
-            (loss, aux), grads = jax.value_and_grad(
-                lambda p: model.loss(p, batch), has_aux=True)(state["params"])
-
-        lr = cosine_schedule(state["step"], lr=hp.lr,
-                             warmup_steps=hp.warmup_steps,
-                             total_steps=hp.total_steps)
-        _, new_opt = adamw_update(grads, state["opt"], state["step"], hp, lr)
-        new_params = cast_params(new_opt["master"], state["params"])
-        new_state = {"step": state["step"] + 1, "params": new_params,
-                     "opt": new_opt}
-
-        backup = _mask(new_opt, backup_pspecs)
-        backup = neighbor_backup(backup, backup_pspecs, mesh, axis=backup_axis)
-
-        metrics = {"loss": loss, **aux, "lr": lr}
-        return new_state, metrics, backup
+        with fsdp_unshard():
+            new_state, loss, aux, lr = body(state, batch)
+            backup = _mask(new_state["opt"], backup_pspecs)
+            backup = neighbor_backup(backup, backup_pspecs, mesh,
+                                     axis=DATA_AXIS)
+        return new_state, {"loss": loss, **aux, "lr": lr}, backup
 
     metrics_shard = None  # replicated scalars; let XLA infer
     backup_shardings = jax.tree.map(
@@ -136,17 +142,7 @@ def build_train_step(
     )
     if donate:
         jit_kwargs["donate_argnums"] = (0,)
-
-    if fsdp_params:
-        from repro.models.modes import fsdp_unshard
-
-        def traced(state, batch):
-            with fsdp_unshard():
-                return train_step(state, batch)
-
-        step_fn = jax.jit(traced, **jit_kwargs)
-    else:
-        step_fn = jax.jit(train_step, **jit_kwargs)
+    step_fn = jax.jit(train_step, **jit_kwargs)
     return StepArtifacts(step_fn, plan, razor, input_pspecs, backup_pspecs)
 
 
@@ -207,62 +203,6 @@ def hierarchical_step_traffic(grad_bytes: float, n_pods: int, pod_size: int,
         state_bytes = float(razor.unique_bytes_per_device_ring) if razor \
             else 0.0
     return TrafficProfile(ici, state_bytes, dcn)
-
-
-def artifacts_traffic(artifacts: StepArtifacts, grad_bytes: float, dp: int
-                      ) -> TrafficProfile:
-    """TrafficProfile for a built train step (razor plan already resolved)."""
-    return step_traffic(grad_bytes, dp, razor=artifacts.razor)
-
-
-# --------------------------------------------------------------------------- #
-# Checkpoint-free replay-compute cost model ("All is Not Lost", PAPERS.md):
-# instead of streaming a lost worker's state over the fabric, its pipeline/DP
-# neighbors re-execute redundant compute to rebuild the shard from their own
-# replicas — recovery then costs worker compute-seconds instead of fabric
-# bytes, which is exactly the currency that stays cheap when a storm has
-# darkened the cross-pod links.
-# --------------------------------------------------------------------------- #
-@dataclass(frozen=True)
-class ReplayCostModel:
-    """Knobs for compute-based (checkpoint-free) recovery.
-
-    `recompute_rate` is how many bytes of lost optimizer/param state one
-    replaying worker can rebuild per second of redundant compute (forward
-    replay at the training step rate, amortized). `replay_overhead`
-    multiplies the state volume: redundant compute interleaves with the
-    replayer's own step, so rebuilding B bytes burns more than B worth of
-    step time. `setup_seconds` is the fixed cost of re-materializing
-    activations and swapping the replay schedule in."""
-    recompute_rate: float = 2e9        # bytes of state rebuilt / s / replayer
-    replay_overhead: float = 1.25      # redundant-compute amplification
-    setup_seconds: float = 0.5         # schedule swap + activation re-mat
-
-
-@dataclass(frozen=True)
-class ReplayCost:
-    """One failed worker's replay bill: `wall_seconds` is the elapsed time
-    with the replayers working in parallel; `compute_seconds` is the total
-    worker compute burned (the resource compute-based recovery spends
-    instead of fabric bytes)."""
-    wall_seconds: float
-    compute_seconds: float
-    bytes_rebuilt: float
-    n_replayers: int
-
-
-def replay_compute_cost(state_bytes: float, n_replayers: int = 2,
-                        model: ReplayCostModel = ReplayCostModel()
-                        ) -> ReplayCost:
-    """Cost of rebuilding `state_bytes` of a lost worker's state by replaying
-    redundant compute on `n_replayers` healthy neighbors. The replayers
-    split the replay evenly, so wall time divides by their count while the
-    total compute burned does not. Submits NO fabric traffic."""
-    n = max(int(n_replayers), 1)
-    burn = state_bytes * model.replay_overhead / model.recompute_rate
-    wall = model.setup_seconds + burn / n
-    return ReplayCost(wall_seconds=wall, compute_seconds=burn,
-                      bytes_rebuilt=float(state_bytes), n_replayers=n)
 
 
 def submit_step_traffic(transport, profile: TrafficProfile, t: float):
